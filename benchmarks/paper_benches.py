@@ -512,98 +512,74 @@ def env_episode(n_ues=500, n_cells=19, n_tti=200):
 SHARDED_MAX_SLOWDOWN = 2.0
 SHARDED_MAX_SLOWDOWN_SMOKE = 4.0
 
-_SHARDED_BENCH_SCRIPT = r"""
-import json, os, sys, time
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=%d "
-                           + os.environ.get("XLA_FLAGS", ""))
-import jax, numpy as np
-sys.path.insert(0, "src")
-from repro.core.crrm import CRRM
-from repro.core.params import CRRM_parameters
 
-n_ues, n_cells, n_tti, n_dev, reps = %d, %d, %d, %d, 3
-# full-buffer PF: every UE active every TTI, so the pf psum (the one
-# cross-shard float reduction) is exercised without the chaotic
-# active-mask flips of bursty traffic -- the 1e-5 equivalence regime.
-kw = dict(n_ues=n_ues, n_cells=n_cells, n_sectors=1, seed=3,
-          pathloss_model_name="UMa", power_W=10.0,
-          scheduler_policy="pf", fairness_p=0.5)
-key = jax.random.PRNGKey(0)
-
-def time_rollout(fns, sim):
-    static, state = sim.episode_static(), sim.init_episode_state(key)
-    out = fns.rollout(static, state, n_tti)           # compile + warm
-    jax.block_until_ready(out)
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        out = fns.rollout(static, state, n_tti)
-        jax.block_until_ready(out)
-        best = min(best, time.perf_counter() - t0)
-    return best / n_tti * 1e6, np.asarray(out[1])
-
-single = CRRM(CRRM_parameters(**kw))
-us_single, t_single = time_rollout(single.episode_fns(), single)
-
-mesh = jax.make_mesh((n_dev,), ("ue",))
-shard = CRRM(CRRM_parameters(**kw))
-us_shard, t_shard = time_rollout(shard.episode_fns(mesh=mesh), shard)
-
-rel = float(np.abs(t_shard - t_single).max()
-            / max(np.abs(t_single).max(), 1.0))
-print(json.dumps(dict(us_per_tti_single=us_single,
-                      us_per_tti_sharded=us_shard,
-                      ratio=us_shard / us_single, max_rel_err=rel)))
-"""
-
-
-def sharded_episode(n_ues=100_000, n_cells=19, n_tti=50, n_dev=2):
-    """us/TTI for a shard_mapped full-buffer PF episode on a forced
-    host-platform mesh vs the single-device rollout; equivalence asserted
-    to 1e-5 and the per-TTI cost ratio gated.  Seeds/updates
-    ``benchmarks/BENCH_sharded.json`` (full mode only)."""
-    import json
-    import os
-    import subprocess
-    import sys
-
+def sharded_episode(n_ues=100_000, n_cells=19, n_tti=50):
+    """us/TTI for a shard_mapped full-buffer PF episode on a UE mesh over
+    every device ``jax.devices()`` reports vs the single-device rollout;
+    equivalence asserted to 1e-5 and the per-TTI cost ratio gated.  Runs
+    in this process: on a CPU host, give the command
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=2``.
+    Seeds/updates ``benchmarks/BENCH_sharded.json`` (full mode only)."""
+    devices = jax.devices()
+    n_dev = len(devices)
+    if n_dev < 2:
+        raise RuntimeError(
+            f"sharded_episode needs at least 2 devices; jax.devices() "
+            f"reports {n_dev} ({devices[0].platform}).  On a CPU host set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=2 in the "
+            f"command's environment")
     if SMOKE:
         n_ues, n_tti = 4096, 20
     gate = SHARDED_MAX_SLOWDOWN_SMOKE if SMOKE else SHARDED_MAX_SLOWDOWN
-    script = _SHARDED_BENCH_SCRIPT % (n_dev, n_ues, n_cells, n_tti, n_dev)
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = subprocess.run([sys.executable, "-c", script], text=True, env=env,
-                         capture_output=True, timeout=3600, cwd=root)
-    if out.returncode != 0:
-        raise RuntimeError(f"sharded bench subprocess failed:\n"
-                           f"{out.stdout}\n{out.stderr}")
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    # full-buffer PF: every UE active every TTI, so the pf psum (the one
+    # cross-shard float reduction) is exercised without the chaotic
+    # active-mask flips of bursty traffic -- the 1e-5 equivalence regime.
+    kw = dict(n_ues=n_ues, n_cells=n_cells, n_sectors=1, seed=3,
+              pathloss_model_name="UMa", power_W=10.0,
+              scheduler_policy="pf", fairness_p=0.5)
+    key = jax.random.PRNGKey(0)
+    reps = 3
+
+    def time_rollout(mesh):
+        sim = CRRM(CRRM_parameters(**kw))
+        fns = sim.episode_fns(mesh=mesh)
+        static, state = sim.episode_static(), sim.init_episode_state(key)
+        out = fns.rollout(static, state, n_tti)       # compile + warm
+        jax.block_until_ready(out)
+        best = float("inf")
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            out = fns.rollout(static, state, n_tti)
+            jax.block_until_ready(out)
+            best = min(best, time.perf_counter() - t0)
+        return best / n_tti * 1e6, np.asarray(out[1])
+
+    us_single, t_single = time_rollout(None)
+    us_shard, t_shard = time_rollout(jax.make_mesh((n_dev,), ("ue",)))
+    rel = float(np.abs(t_shard - t_single).max()
+                / max(np.abs(t_single).max(), 1.0))
+    ratio = us_shard / us_single
     print(f"# sharded_episode: {n_ues} UEs x {n_tti} TTIs on {n_dev} "
-          f"host devices: single {rec['us_per_tti_single']:.1f} us/TTI, "
-          f"sharded {rec['us_per_tti_sharded']:.1f} us/TTI "
-          f"({rec['ratio']:.2f}x; gate {gate}x), "
-          f"max rel err {rec['max_rel_err']:.2e}")
-    assert rec["max_rel_err"] < 1e-5, (
-        f"sharded rollout deviates from single device: "
-        f"{rec['max_rel_err']:.3e}")
-    assert rec["ratio"] < gate, (
-        f"sharded episode {rec['ratio']:.2f}x slower per TTI than single "
+          f"{devices[0].platform} devices: single {us_single:.1f} us/TTI, "
+          f"sharded {us_shard:.1f} us/TTI ({ratio:.2f}x; gate {gate}x), "
+          f"max rel err {rel:.2e}")
+    assert rel < 1e-5, (
+        f"sharded rollout deviates from single device: {rel:.3e}")
+    assert ratio < gate, (
+        f"sharded episode {ratio:.2f}x slower per TTI than single "
         f"device (gate {gate}x)")
     if not SMOKE:
         _write_record("BENCH_sharded.json", {
             "bench": "sharded_episode", "n_ues": n_ues,
             "n_cells": n_cells, "n_tti": n_tti, "n_devices": n_dev,
-            "us_per_tti_single": round(rec["us_per_tti_single"], 2),
-            "us_per_tti_sharded": round(rec["us_per_tti_sharded"], 2),
-            "sharded_vs_single_ratio": round(rec["ratio"], 3),
-            "max_rel_err": rec["max_rel_err"],
+            "us_per_tti_single": round(us_single, 2),
+            "us_per_tti_sharded": round(us_shard, 2),
+            "sharded_vs_single_ratio": round(ratio, 3),
+            "max_rel_err": rel,
             "gated_metric": "sharded_vs_single_ratio",
             "gate_direction": "max", "gate": gate,
             "smoke_gate": SHARDED_MAX_SLOWDOWN_SMOKE})
-    return "sharded_episode_cost_ratio", rec["us_per_tti_sharded"], \
-        rec["ratio"]
+    return "sharded_episode_cost_ratio", us_shard, ratio
 
 
 # -- smart update INSIDE the compiled TTI engine (ISSUE 5 tentpole) ----------

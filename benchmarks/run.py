@@ -23,6 +23,7 @@ import traceback
 
 def main(argv=None) -> None:
     from benchmarks import paper_benches
+    from repro.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
@@ -33,6 +34,7 @@ def main(argv=None) -> None:
     ap.add_argument("--json", action="store_true",
                     help="one JSON document on stdout (detail -> stderr)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     paper_benches.SMOKE = args.smoke
     benches = [b for b in paper_benches.ALL if args.only in b.__name__]
     if not benches:
@@ -67,15 +69,6 @@ def main(argv=None) -> None:
                 if not args.json:
                     print(f"{bench.__name__},FAILED,-")
                 traceback.print_exc(file=sys.stderr)
-        # roofline summary from dry-run artifacts, if present
-        try:
-            import os
-            if os.path.isdir("artifacts/dryrun"):
-                from repro.analysis import roofline
-                print("# --- roofline table (artifacts/dryrun) ---")
-                roofline.main("artifacts/dryrun")
-        except Exception:
-            traceback.print_exc(file=sys.stderr)
     if args.json:
         json.dump({"smoke": bool(args.smoke), "failures": failures,
                    "results": results}, sys.stdout, indent=2,

@@ -1,9 +1,9 @@
-"""Roofline model: the three-term analysis over dry-run artifacts.
+"""Roofline model: the three-term analysis over compiled-program artifacts.
 
-Hardware constants (TPU v5e-class target, per assignment):
-    peak bf16 compute   197 TFLOP/s / chip
-    HBM bandwidth       819 GB/s / chip
-    ICI link bandwidth  ~50 GB/s / link
+Peaks come from :data:`PEAKS`, one row per ``device_kind`` as JAX reports
+it (``jax.devices()[0].device_kind``), each with its published source.  An
+artifact names the device kind it was compiled for; a kind missing from
+the table is an error (:func:`peaks`), never a default.
 
     compute term    = HLO_FLOPs / (chips * peak)
     memory term     = HLO_bytes / (chips * HBM_bw)
@@ -12,19 +12,41 @@ Hardware constants (TPU v5e-class target, per assignment):
 
 The dominant term is the projected step time's lower bound; the roofline
 fraction we report for the hillclimb is useful_model_flops / (dominant_term *
-chips * peak).  Run as a module to print the table from artifacts/dryrun:
-
-    PYTHONPATH=src python -m repro.analysis.roofline [--dir artifacts/dryrun]
+chips * peak).  ``repro.obs.report`` writes the table for the compiled
+episode rollout.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
-import os
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s / chip
-ICI_BW = 50e9            # bytes/s / link
+
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float        # peak bf16 FLOP/s per chip
+    hbm_bw: float       # HBM bytes/s per chip
+    ici_bw: float       # inter-chip bytes/s per link
+    source: str
+
+
+#: published per-chip peaks, keyed by ``device_kind``
+PEAKS = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI over 4 links"),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; raises for a kind with no
+    row in :data:`PEAKS`."""
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; add a "
+            f"row with its source to roofline.PEAKS (known: "
+            f"{sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 
 @dataclasses.dataclass
@@ -35,6 +57,7 @@ class Roofline:
     model_flops: float
     hlo_flops: float
     chips: int
+    peak_flops: float
 
     @property
     def dominant(self) -> str:
@@ -55,7 +78,7 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """Useful model FLOPs over what the chips could do in the bound
         time -- the score we hillclimb."""
-        cap = self.bound_s * self.chips * PEAK_FLOPS
+        cap = self.bound_s * self.chips * self.peak_flops
         return self.model_flops / cap if cap > 0 else 0.0
 
 
@@ -70,17 +93,20 @@ def model_flops_decode(n_params_active: float, tokens: float) -> float:
 def from_artifact(art: dict) -> Roofline:
     """Prefers the analytic executed-FLOPs/bytes model (exact per-layer
     formulas; XLA cost_analysis counts loop bodies once -- see
-    analysis/flops.py) and falls back to raw cost_analysis numbers."""
+    analysis/flops.py) and falls back to raw cost_analysis numbers.  The
+    peaks are those of ``art["device_kind"]`` (:func:`peaks`)."""
+    pk = peaks(art["device_kind"])
     chips = art["n_devices"]
     flops = art.get("analytic_flops") or art["hlo_flops"]
     bytes_ = art.get("analytic_bytes") or art["hlo_bytes"]
     return Roofline(
-        compute_s=flops / (chips * PEAK_FLOPS),
-        memory_s=bytes_ / (chips * HBM_BW),
-        collective_s=art["collective_wire_bytes"] / ICI_BW,
+        compute_s=flops / (chips * pk.flops),
+        memory_s=bytes_ / (chips * pk.hbm_bw),
+        collective_s=art["collective_wire_bytes"] / pk.ici_bw,
         model_flops=art["model_flops"],
         hlo_flops=flops,
         chips=chips,
+        peak_flops=pk.flops,
     )
 
 
@@ -90,28 +116,3 @@ def format_row(name: str, art: dict) -> str:
             f"{r.collective_s*1e3:.1f} | {r.dominant} | "
             f"{r.useful_flops_ratio:.2f} | {r.roofline_fraction:.3f} |")
 
-
-def main(art_dir: str = "artifacts/dryrun"):
-    print("| cell | compute ms | memory ms | collective ms | dominant | "
-          "useful/HLO | roofline frac |")
-    print("|---|---|---|---|---|---|---|")
-    for root, _, files in sorted(os.walk(art_dir)):
-        for f in sorted(files):
-            if not f.endswith(".json"):
-                continue
-            with open(os.path.join(root, f)) as fh:
-                art = json.load(fh)
-            if art.get("skipped"):
-                name = os.path.relpath(os.path.join(root, f), art_dir)
-                print(f"| {name} | - | - | - | skipped: "
-                      f"{art['reason'][:40]} | - | - |")
-                continue
-            name = os.path.relpath(os.path.join(root, f),
-                                   art_dir).replace(".json", "")
-            print(format_row(name, art))
-
-
-if __name__ == "__main__":
-    import sys
-    main(sys.argv[sys.argv.index("--dir") + 1]
-         if "--dir" in sys.argv else "artifacts/dryrun")

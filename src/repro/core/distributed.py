@@ -37,23 +37,12 @@ from jax.sharding import PartitionSpec as P
 
 from repro.sim import phy
 
-# shard_map was promoted out of jax.experimental and pvary introduced in
-# newer jax; alias both so the module runs on the container's pinned version
-# (where shard_map carries need no device-varying typing -- pvary is a no-op).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-#: axis_size appeared alongside pvary; psum of 1 is the portable equivalent
-_axis_size = getattr(jax.lax, "axis_size", lambda ax: jax.lax.psum(1, ax))
-
 
 def _axis_index(axes) -> jnp.ndarray:
     """Linearised shard index over one or more mesh axes (row-major)."""
     idx = jnp.int32(0)
     for ax in axes:
-        idx = idx * _axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return idx
 
 
@@ -143,7 +132,7 @@ def make_materialized_step(mesh, pathgain_fn: Callable, noise_w: float,
 
     in_specs = (P(ue_axis, None), P(cell_axis, None), P(cell_axis, None))
     out_specs = (P(ue_axis, None), P(ue_axis), P(ue_axis, None))
-    return _shard_map(step, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(step, mesh=mesh, in_specs=in_specs,
                       out_specs=out_specs)
 
 
@@ -185,7 +174,7 @@ def _stream_over_cells(U_loc, C_loc, P_loc, pathgain_fn, tile: int,
     if vary_axes:
         # inside shard_map the scan carry must be typed device-varying
         init = jax.tree_util.tree_map(
-            lambda x: _pvary(x, tuple(vary_axes)), init)
+            lambda x: jax.lax.pcast(x, tuple(vary_axes), to="varying"), init)
     (total, best_val, best_idx, w_best), _ = jax.lax.scan(
         body, init, (C_t, P_t, jnp.arange(n_tiles)))
     return total, best_val, best_idx, w_best
@@ -218,7 +207,7 @@ def make_streaming_step(mesh, pathgain_fn: Callable, noise_w: float,
 
     in_specs = (P(ue_axis, None), P(cell_axis, None), P(cell_axis, None))
     out_specs = (P(ue_axis, None), P(ue_axis), P(ue_axis, None))
-    return _shard_map(step, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(step, mesh=mesh, in_specs=in_specs,
                       out_specs=out_specs)
 
 
@@ -281,5 +270,5 @@ def make_incremental_rows_step(mesh, pathgain_fn: Callable, noise_w: float,
                 P(ue_axis), P(None), P(None, None))
     out_specs = (P(ue_axis, None), P(ue_axis, None), P(ue_axis, None),
                  P(ue_axis), P(ue_axis), P(ue_axis, None))
-    return _shard_map(step, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(step, mesh=mesh, in_specs=in_specs,
                       out_specs=out_specs)

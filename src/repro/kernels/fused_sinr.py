@@ -15,19 +15,29 @@ so the (N, M) distance/gain/RSRP matrices never touch HBM.  Tie-break matches
 ``jnp.argmax`` (lowest cell index wins).
 
 Per-link fading streams through the same tile pipeline: a ``fading`` tile --
-``(bn, bm)`` wideband or ``(bn, bm, K)`` per-RB -- multiplies the gain tile
+``(bn, bm)`` wideband or ``(K, bn, bm)`` per-RB -- multiplies the gain tile
 exactly as ``radio.apply_fading`` does, and ``attach_on_mean`` reproduces the
 ``attach_ignores_fading`` regime by ranking servers on the *unfaded* RSRP row
 sum while still reporting the faded serving row.
+
+Layout.  Every value the kernel computes is a 2-D ``(bn, bm)`` tile (UEs on
+sublanes, cells on lanes) or a ``(bn, 1)`` / ``(1, bm)`` edge of one, which
+is what the TPU's vector layout holds natively.  The frequency axis K (small
+and static) is an unrolled Python loop, never an array axis: per-cell inputs
+arrive as ``(3 | K, 1, M)`` row stacks and per-RB fading as ``(K, N, M)``,
+so each ``k`` is a leading-axis index (``kernels/ops.py`` does the
+transposes).  Coordinate columns, the argmax's serving row and the ``(bn, K)``
+output blocks are built with one-hot selects instead of gathers or lane
+slices, which are exact (one non-zero term per sum).
 
 Grid: (UE tiles, cell tiles); the cell dimension is `arbitrary` (sequential)
 because every step read-modify-writes the same output block.  The pathloss
 strategy is traced *into* the kernel as pure jnp (any 38.901 model works).
 
-VMEM per step (defaults bn=256, bm=512, K<=8): the (bn, bm) gain tile +
-(bn, bm, K) RSRP tile + optional (bn, bm[, K]) fading tile ~= 0.5 + 4 + 4 MiB
--- inside budget; the MXU computes the distance contraction as in
-pairwise_dist.
+VMEM per step, as the TPU compiler allocates it (``tests/test_tpu_compile.py``
+pins the kernels' compiles for a v5e; DESIGN.md §Million-UE-scaling has the
+figures): the double-buffered input blocks, the ``(bn, bm)`` temporaries of
+the gain chain, and the lane-padded ``(bn, K)`` output blocks.
 """
 from __future__ import annotations
 
@@ -38,15 +48,39 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: renamed TPUCompilerParams -> CompilerParams in newer jax; accept both so
-#: the kernel builds against the container's pinned version too
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 _NEG = -3.4e38  # python float: jnp constants would be captured consts
 
 
-def _make_kernel(pathgain_fn, n_sectors: int, bm: int, mxu: bool = True,
+def _column(x, i):
+    """Column ``i`` of a ``(rows, w)`` tile as ``(rows, 1)``: a one-hot
+    select and lane sum (exact: one non-zero term)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.sum(jnp.where(lane == i, x, 0.0), axis=1, keepdims=True)
+
+
+def _atan2(y, x):
+    """float32 ``arctan2`` from the ops Mosaic lowers (it has no atan).
+
+    Octant reduction to ``t = min/max`` in [0, 1], a second reduction
+    about tan(pi/8), then the Cephes ``atanf`` minimax polynomial (about
+    2e-7 relative error, i.e. an ulp or two of ``jnp.arctan2``).
+    ``atan2(0, 0) = 0`` as in ``jnp.arctan2``.
+    """
+    ax, ay = jnp.abs(x), jnp.abs(y)
+    hi, lo = jnp.maximum(ax, ay), jnp.minimum(ax, ay)
+    t = lo / jnp.where(hi == 0.0, 1.0, hi)
+    mid = t > 0.41421356237309503                  # tan(pi/8)
+    z = jnp.where(mid, (t - 1.0) / (t + 1.0), t)
+    z2 = z * z
+    p = ((((8.05374449538e-2 * z2 - 1.38776856032e-1) * z2
+           + 1.99777106478e-1) * z2 - 3.33329491539e-1) * z2 * z + z)
+    a = jnp.where(mid, 0.7853981633974483 + p, p)
+    a = jnp.where(ay > ax, 1.5707963267948966 - a, a)
+    a = jnp.where(x < 0.0, 3.141592653589793 - a, a)
+    return jnp.where(y < 0.0, -a, a)
+
+
+def _make_kernel(pathgain_fn, n_sectors: int, bm: int, k: int,
                  fading: str | None = None, attach_on_mean: bool = False):
     def kernel(*refs):
         if fading is None:
@@ -65,90 +99,88 @@ def _make_kernel(pathgain_fn, n_sectors: int, bm: int, mxu: bool = True,
             barg_ref[...] = jnp.zeros_like(barg_ref)
             wbest_ref[...] = jnp.zeros_like(wbest_ref)
 
-        u = u_ref[...]                    # (bn, 3)
-        c = c_ref[...]                    # (bm, 3)
-        p = p_ref[...]                    # (bm, K)
+        u = u_ref[...]                               # (bn, 3)
+        ux, uy, uz = (_column(u, i) for i in range(3))   # (bn, 1) each
+        cx, cy, cz = c_ref[0], c_ref[1], c_ref[2]    # (1, bm) each
 
-        if mxu:
-            # MXU decomposition: fast, ~1e-5 relative error from the
-            # catastrophic cancellation in |u|^2+|c|^2-2u.c (documented).
-            dot3 = jax.lax.dot_general(u, c, (((1,), (1,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-            un3 = jnp.sum(u * u, axis=1, keepdims=True)
-            cn3 = jnp.sum(c * c, axis=1, keepdims=True).T
-            uz, cz = u[:, 2:3], c[:, 2:3]
-            dz2 = uz * uz + (cz * cz).T - 2.0 * (uz * cz.T)
-            sq3 = jnp.maximum(un3 + cn3 - 2.0 * dot3, 0.0)
-            d3d = jnp.sqrt(sq3)
-            d2d = jnp.sqrt(jnp.maximum(sq3 - dz2, 0.0))
-        else:
-            # VPU broadcast-difference mirroring radio.compute_distances
-            # operation for operation (d3d built FROM d2d, not from the raw
-            # squared sum) so the kernel is bit-identical to the reference
-            dx = u[:, None, 0] - c[None, :, 0]
-            dy = u[:, None, 1] - c[None, :, 1]
-            dz = u[:, None, 2] - c[None, :, 2]
-            d2d = jnp.sqrt(dx * dx + dy * dy)
-            d3d = jnp.sqrt(d2d * d2d + dz * dz)
+        # broadcast difference mirroring radio.compute_distances operation
+        # for operation (d3d built FROM d2d, not from the raw squared sum)
+        dx = ux - cx
+        dy = uy - cy
+        dz = uz - cz
+        d2d = jnp.sqrt(dx * dx + dy * dy)
+        d3d = jnp.sqrt(d2d * d2d + dz * dz)
 
         # -- G: pluggable pathloss strategy (traced jnp) -------------------
-        g = pathgain_fn(d2d, d3d, c[:, 2][None, :], u[:, 2][:, None])
+        g = pathgain_fn(d2d, d3d, cz, uz)
         if n_sectors > 1:
-            # 3GPP horizontal pattern, inlined for fusion
-            dx = u[:, 0:1] - c[:, 0].reshape(1, -1)
-            dy = u[:, 1:2] - c[:, 1].reshape(1, -1)
-            az = jnp.arctan2(dy, dx)
-            off = az - bore_ref[...][:, 0][None, :]
-            off = jnp.arctan2(jnp.sin(off), jnp.cos(off))
+            # 3GPP horizontal pattern, inlined for fusion; the offset from
+            # boresight wraps to [-pi, pi] by whole turns
+            off = _atan2(dy, dx) - bore_ref[...]
+            off = off - 6.283185307179586 * jnp.round(
+                off * 0.15915494309189535)
             phi3 = 1.1344640137963142  # 65 deg in radians
             att = jnp.minimum(12.0 * (off / phi3) ** 2, 30.0)
             g = g * jnp.power(10.0, -0.1 * att)
 
-        # -- RSRP + online reductions ---------------------------------------
-        if fading is None:
-            r = g[:, :, None] * p[None, :, :]        # (bn, bm, K)
-            meas = r.sum(axis=2)
-        elif fading == "wide":
+        # -- RSRP per frequency chunk (K unrolled, each a 2-D tile) --------
+        if fading == "wide":
             gf = g * fad_ref[...]                    # apply_fading, 2-D
-            r = gf[:, :, None] * p[None, :, :]
-            meas = (g[:, :, None] * p[None, :, :]).sum(axis=2) \
-                if attach_on_mean else r.sum(axis=2)
-        else:                                        # "rb": per-RB fading
-            g3 = g[:, :, None] * fad_ref[...]        # apply_fading, 3-D
-            r = g3 * p[None, :, :]
-            meas = (g[:, :, None] * p[None, :, :]).sum(axis=2) \
-                if attach_on_mean else r.sum(axis=2)
-        total_ref[...] += r.sum(axis=1)
-        t_max = meas.max(axis=1)
-        t_arg = jnp.argmax(meas, axis=1)
-        t_w = jnp.take_along_axis(r, t_arg[:, None, None], axis=1)[:, 0, :]
-        prev = bval_ref[...][:, 0]
-        better = t_max > prev
-        bval_ref[...] = jnp.where(better, t_max, prev)[:, None]
-        barg_ref[...] = jnp.where(
-            better, t_arg.astype(jnp.int32) + j * bm,
-            barg_ref[...][:, 0])[:, None]
-        wbest_ref[...] = jnp.where(better[:, None], t_w, wbest_ref[...])
+            r = [gf * p_ref[kk] for kk in range(k)]
+        elif fading == "rb":
+            r = [(g * fad_ref[kk]) * p_ref[kk] for kk in range(k)]
+        else:
+            r = [g * p_ref[kk] for kk in range(k)]
+        if attach_on_mean:
+            rank = [g * p_ref[kk] for kk in range(k)]
+        else:
+            rank = r
+        meas = rank[0]
+        for rk in rank[1:]:
+            meas = meas + rk
+
+        # -- online reductions: tile argmax by max + lowest matching lane --
+        lane = jax.lax.broadcasted_iota(jnp.int32, meas.shape, 1)
+        t_max = jnp.max(meas, axis=1, keepdims=True)             # (bn, 1)
+        t_arg = jnp.min(jnp.where(meas == t_max, lane, bm), axis=1,
+                        keepdims=True)                           # (bn, 1)
+        onehot = lane == t_arg
+        kcol = jax.lax.broadcasted_iota(jnp.int32, total_ref.shape, 1)
+        tot = jnp.zeros(total_ref.shape, jnp.float32)
+        t_w = jnp.zeros(wbest_ref.shape, jnp.float32)
+        for kk in range(k):
+            put = kcol == kk
+            tot = jnp.where(put, jnp.sum(r[kk], axis=1, keepdims=True), tot)
+            t_w = jnp.where(put, jnp.sum(jnp.where(onehot, r[kk], 0.0),
+                                         axis=1, keepdims=True), t_w)
+        total_ref[...] += tot
+        prev = bval_ref[...]
+        better = t_max > prev                                    # (bn, 1)
+        bval_ref[...] = jnp.where(better, t_max, prev)
+        barg_ref[...] = jnp.where(better, t_arg + j * bm, barg_ref[...])
+        wbest_ref[...] = jnp.where(better, t_w, wbest_ref[...])
 
     return kernel
 
 
 @partial(jax.jit,
          static_argnames=("pathgain_fn", "n_sectors", "bn", "bm", "interpret",
-                          "mxu", "attach_on_mean"))
-def fused_sinr_accumulate(U, C, Pw, boresight, fad=None, *, pathgain_fn,
-                          n_sectors: int = 1, bn: int = 256, bm: int = 512,
-                          interpret: bool = False, mxu: bool = False,
+                          "attach_on_mean"))
+def fused_sinr_accumulate(U, C_rows, P_rows, bore_row, fad=None, *,
+                          pathgain_fn, n_sectors: int = 1, bn: int = 256,
+                          bm: int = 512, interpret: bool = False,
                           attach_on_mean: bool = False):
     """Run the fused accumulator.  Returns (total, best_val, best_idx, w_best).
 
-    Shapes: U (N, 3), C (M, 3), Pw (M, K), boresight (M, 1), fad None /
-    (N, M) wideband / (N, M, K) per-RB.  N % bn == 0 and M % bm == 0
-    (ops.py pads; padded cells need power 0 and padded fading 0).
+    Kernel layout (``kernels/ops.py`` converts): U (N, 3), C_rows (3, 1, M)
+    cell coordinates, P_rows (K, 1, M) per-chunk powers, bore_row (1, M),
+    fad None / (N, M) wideband / (K, N, M) per-RB.  Outputs are (N, K),
+    (N, 1), (N, 1) int32, (N, K).  N % bn == 0 and M % bm == 0 (ops.py
+    pads; padded cells need power 0 and padded fading 0).
     ``attach_on_mean`` ranks servers on the unfaded RSRP row sum
     (``attach_ignores_fading``); it requires ``fad``.
     """
-    n, m, k = U.shape[0], C.shape[0], Pw.shape[1]
+    n, m, k = U.shape[0], C_rows.shape[2], P_rows.shape[0]
     assert n % bn == 0 and m % bm == 0, (n, m, bn, bm)
     if fad is None:
         fading = None
@@ -158,22 +190,22 @@ def fused_sinr_accumulate(U, C, Pw, boresight, fad=None, *, pathgain_fn,
         assert fad.shape == (n, m), (fad.shape, n, m)
     else:
         fading = "rb"
-        assert fad.shape == (n, m, k), (fad.shape, n, m, k)
+        assert fad.shape == (k, n, m), (fad.shape, k, n, m)
     grid = (n // bn, m // bm)
-    kernel = _make_kernel(pathgain_fn, n_sectors, bm, mxu, fading,
+    kernel = _make_kernel(pathgain_fn, n_sectors, bm, k, fading,
                           attach_on_mean)
     in_specs = [
         pl.BlockSpec((bn, 3), lambda i, j: (i, 0)),
-        pl.BlockSpec((bm, 3), lambda i, j: (j, 0)),
-        pl.BlockSpec((bm, k), lambda i, j: (j, 0)),
-        pl.BlockSpec((bm, 1), lambda i, j: (j, 0)),
+        pl.BlockSpec((3, 1, bm), lambda i, j: (0, 0, j)),
+        pl.BlockSpec((k, 1, bm), lambda i, j: (0, 0, j)),
+        pl.BlockSpec((1, bm), lambda i, j: (0, j)),
     ]
-    operands = [U, C, Pw, boresight]
+    operands = [U, C_rows, P_rows, bore_row]
     if fading == "wide":
         in_specs.append(pl.BlockSpec((bn, bm), lambda i, j: (i, j)))
         operands.append(fad)
     elif fading == "rb":
-        in_specs.append(pl.BlockSpec((bn, bm, k), lambda i, j: (i, j, 0)))
+        in_specs.append(pl.BlockSpec((k, bn, bm), lambda i, j: (0, i, j)))
         operands.append(fad)
     out_shape = [
         jax.ShapeDtypeStruct((n, k), jnp.float32),   # total
@@ -192,7 +224,8 @@ def fused_sinr_accumulate(U, C, Pw, boresight, fad=None, *, pathgain_fn,
             pl.BlockSpec((bn, k), lambda i, j: (i, 0)),
         ],
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="fused_sinr",
     )(*operands)

@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-These handle padding to tile multiples, interpret-mode fallback on CPU (the
-container has no TPU; ``interpret=True`` executes the kernel body in Python
-for correctness validation), and the final cheap SINR math on the
-kernel-accumulated O(N) state.
+These handle padding to tile multiples, the kernels' data layout, interpret
+mode off the TPU (``interpret=True`` executes the kernel body with jnp ops
+for correctness validation on any backend), and the final cheap SINR math on
+the kernel-accumulated O(N) state.
 """
 from __future__ import annotations
 
@@ -16,8 +16,10 @@ from repro.kernels import fused_sinr as _fused
 from repro.kernels import pairwise_dist as _dist
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+def _off_tpu() -> bool:
+    """Interpret mode is the default exactly where there is no TPU; on a
+    TPU the kernels always compile (a refusal raises to the caller)."""
+    return jax.default_backend() != "tpu"
 
 
 def _pad_axis(x, mult, axis=0, fill=0.0):
@@ -37,7 +39,7 @@ def _pad_rows(x, mult, fill=0.0):
 def pairwise_dist(U, C, *, bn: int = 256, bm: int = 512, interpret=None):
     """(d2d, d3d) via the tiled MXU kernel; pads then slices."""
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = _off_tpu()
     n, m = U.shape[0], C.shape[0]
     bn = min(bn, max(8, n))
     bm = min(bm, max(8, m))
@@ -50,7 +52,7 @@ def pairwise_dist(U, C, *, bn: int = 256, bm: int = 512, interpret=None):
 def fused_sinr(U, C, Pw, *, pathgain_fn, noise_w: float, boresight=None,
                fad=None, attach_on_mean: bool = False,
                n_sectors: int = 1, bn: int = 256, bm: int = 512,
-               interpret=None, mxu: bool = False):
+               interpret=None):
     """Fused D->G->RSRP->w/u->SINR pipeline.
 
     Returns (gamma, a, w, u) exactly like ``ref.fused_sinr_ref`` but with
@@ -64,25 +66,33 @@ def fused_sinr(U, C, Pw, *, pathgain_fn, noise_w: float, boresight=None,
     point serves the dirty-row incremental backend: callers gather the
     dirty UE slab (rows of U and fad) and scatter the returned rows back
     (``radio.radio_update_rows_fused``).
+
+    A tile dimension larger than the problem shrinks to it (a block equal
+    to the whole axis satisfies the TPU's (8, 128) tiling at any size);
+    otherwise ``bn`` must be a multiple of 8 and ``bm`` of 128 on the chip.
+    This wrapper converts to the kernel's layout (cells and frequency
+    chunks as leading-axis row stacks, per-RB fading as (K, N, M)).
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = _off_tpu()
     n, m = U.shape[0], C.shape[0]
-    bn = min(bn, max(8, n))
-    bm = min(bm, max(8, m))
+    bn = min(bn, n)
+    bm = min(bm, m)
     Up = _pad_rows(U, bn)
     Cp = _pad_rows(C, bm, fill=1e9)
     Pp = _pad_rows(Pw, bm, fill=0.0)
     if boresight is None:
-        bore = jnp.zeros((Cp.shape[0], 1), jnp.float32)
+        bore = jnp.zeros((1, Cp.shape[0]), jnp.float32)
     else:
-        bore = _pad_rows(boresight.reshape(-1, 1), bm)
+        bore = _pad_rows(boresight.reshape(-1), bm).reshape(1, -1)
     if fad is not None:
         fad = _pad_axis(_pad_axis(fad, bn, axis=0), bm, axis=1)
+        if fad.ndim == 3:
+            fad = jnp.moveaxis(fad, 2, 0)          # (K, N, M)
     total, bval, barg, wbest = _fused.fused_sinr_accumulate(
-        Up, Cp, Pp, bore, fad, pathgain_fn=pathgain_fn, n_sectors=n_sectors,
-        bn=bn, bm=bm, interpret=interpret, mxu=mxu,
-        attach_on_mean=attach_on_mean)
+        Up, Cp.T[:, None, :], Pp.T[:, None, :], bore, fad,
+        pathgain_fn=pathgain_fn, n_sectors=n_sectors, bn=bn, bm=bm,
+        interpret=interpret, attach_on_mean=attach_on_mean)
     total, barg, wbest = total[:n], barg[:n, 0], wbest[:n]
     u = total - wbest
     gamma = wbest / (noise_w + u)

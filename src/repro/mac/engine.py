@@ -96,8 +96,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PSpec
 
-from repro.core.distributed import (_axis_index, _global_best, _pvary,
-                                    _shard_map)
+from repro.core.distributed import _axis_index, _global_best
 from repro.mac import scheduler as mac_sched
 from repro.obs.telemetry import Telemetry, tti_telemetry
 from repro.sim import deploy, faults as sim_faults, mobility, radio
@@ -346,7 +345,10 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
     Equivalence contract vs single device: attachment/serving/positions
     bitwise, float outputs to 1e-5 (the psum reorders the per-cell
     interference sum) -- the same contract the UE-only mesh carries for
-    pf (tests/test_smart_update_scan.py, subprocess case).
+    pf (tests/test_smart_update_scan.py, subprocess case).  That per-UE
+    bound holds at test sizes; with thousands of UEs per cell the
+    reordered sums move per-UE rates by more than 1e-5 while network
+    KPIs stay within it (DESIGN.md §Million-UE-scaling).
 
     ``inc_backend`` routes the incremental mode's dirty-row recompute:
     ``None``/``"xla"`` is the legacy ``radio.radio_update_rows``;
@@ -355,8 +357,8 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
     gain/RSRP, interpret mode on CPU) and raises where the kernel
     cannot express the regime (handover tables, cell-sharded meshes,
     non-stock sector patterns); ``"auto"`` picks Pallas exactly when
-    expressible and a real accelerator passed the capability probe,
-    else XLA.
+    expressible and the default backend is a TPU
+    (``radio.pallas_available``), else XLA.
 
     The trace-time feature switches (mobility / per-TTI fading / HARQ /
     handover / per-RB grid / ``radio_mode`` / ``mobility_move_frac``) are
@@ -1211,11 +1213,11 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
     def revar(state):
         """Re-establish the claimed replication of the scalar carry slots.
 
-        The scan carry is typed device-varying as a whole (``pvary``), but
-        the scalar slots (cursor, key, t) evolve identically on every
-        shard; a ``pmax`` both proves and restores their replication so
-        they can leave the shard_map under a replicated out-spec.  No-ops
-        on jax versions without varying-type tracking.
+        The scan carry is typed device-varying as a whole
+        (``lax.pcast(..., to="varying")``), but the scalar slots (cursor,
+        key, t) evolve identically on every shard; a ``pmax`` both proves
+        and restores their replication so they can leave the shard_map
+        under a replicated out-spec.
         """
         fix = lambda x: jax.lax.pmax(x, mesh_axes)
         out = state._replace(rr_cursor=fix(state.rr_cursor),
@@ -1230,7 +1232,7 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         # rule.  The kwarg spelling differs across jax versions.
         for kw in ({"check_rep": False}, {"check_vma": False}, {}):
             try:
-                return _shard_map(fn, mesh=mesh, in_specs=in_specs,
+                return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                                   out_specs=out_specs, **kw)
             except TypeError:       # pragma: no cover - version dependent
                 continue
@@ -1258,7 +1260,7 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         def one(static, state, *extra):
             act, fp = split_extra(has_act, extra)
             state = jax.tree_util.tree_map(
-                lambda x: _pvary(x, mesh_axes), state)
+                lambda x: jax.lax.pcast(x, mesh_axes, to="varying"), state)
             h, rs0 = setup(static, state, act)
             state, tput, _, telem = tti_step(h, static, state, act, rs0, fp)
             if telemetry:
@@ -1278,7 +1280,7 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         def roll(static, state, *extra):
             act, fp = split_extra(has_act, extra)
             init = jax.tree_util.tree_map(
-                lambda x: _pvary(x, mesh_axes), state)
+                lambda x: jax.lax.pcast(x, mesh_axes, to="varying"), state)
             h, rs0 = setup(static, init, act)
 
             def body(carry, _):

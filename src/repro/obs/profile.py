@@ -28,16 +28,18 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 
-#: process-wide XLA backend-compile count, fed by the jax.monitoring
-#: duration event '/jax/core/compile/backend_compile_duration' (one per
-#: compilation).  Registered lazily, once; CompileCounter reads deltas.
-_COMPILE_EVENTS = {"count": 0}
+#: process-wide XLA backend-compile count and seconds, fed by the
+#: jax.monitoring duration event '/jax/core/compile/backend_compile_duration'
+#: (one per compilation; a persistent-cache hit reports its short load).
+#: Registered lazily, once; CompileCounter reads deltas.
+_COMPILE_EVENTS = {"count": 0, "seconds": 0.0}
 _LISTENER_STATE = {"registered": False, "available": None}
 
 
 def _on_duration(name: str, secs: float, **kw) -> None:
     if name.endswith("backend_compile_duration"):
         _COMPILE_EVENTS["count"] += 1
+        _COMPILE_EVENTS["seconds"] += secs
 
 
 def _ensure_listener() -> bool:
@@ -97,21 +99,26 @@ class CompileCounter:
     jitted function recompiles per call, silently trading the one-program
     scan for per-call tracing.  ``supported`` is False on jax builds
     without the monitoring event stream -- the count then stays 0 and
-    callers should skip the assertion (tests do).
+    callers should skip the assertion (tests do).  ``seconds`` is the
+    backend compile time spent in the region.
     """
 
     def __init__(self):
         self.supported = _ensure_listener()
-        self._base = 0
+        self._base = self._base_s = 0
         self.count = 0
+        self.seconds = 0.0
 
     def __enter__(self) -> "CompileCounter":
         self._base = _COMPILE_EVENTS["count"]
+        self._base_s = _COMPILE_EVENTS["seconds"]
         self.count = 0
+        self.seconds = 0.0
         return self
 
     def __exit__(self, *exc) -> None:
         self.count = _COMPILE_EVENTS["count"] - self._base
+        self.seconds = _COMPILE_EVENTS["seconds"] - self._base_s
 
 
 def executable_cache_size(fn) -> Optional[int]:

@@ -110,6 +110,7 @@ def episode_report(sim, n_tti: int, *, mesh=None, scenario: str = "",
         "model_flops": model_flops_episode(
             sim.n_ues, sim.n_cells, sim.params.n_freq, n_tti),
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
     }
     try:
         args = (static, state, n_tti) if action is None else \
@@ -126,7 +127,12 @@ def episode_report(sim, n_tti: int, *, mesh=None, scenario: str = "",
 
 
 def roofline_table(artifacts: dict) -> str:
-    """Markdown roofline table over ``{name: artifact}`` dicts."""
+    """Markdown roofline table over ``{name: artifact}`` dicts.
+
+    An artifact compiled for a device with no published peaks
+    (``roofline.PEAKS``, e.g. the CPU) keeps its counts in the JSON and
+    renders a row that says so, not times from another device's peaks.
+    """
     lines = ["| cell | compute ms | memory ms | collective ms | dominant "
              "| useful/HLO | roofline frac |",
              "|---|---|---|---|---|---|---|"]
@@ -135,6 +141,9 @@ def roofline_table(artifacts: dict) -> str:
         if art.get("skipped"):
             lines.append(f"| {name} | - | - | - | skipped: "
                          f"{art.get('reason', '')[:40]} | - | - |")
+        elif art["device_kind"] not in roofline.PEAKS:
+            lines.append(f"| {name} | - | - | - | no peaks for "
+                         f"{art['device_kind']!r} | - | - |")
         else:
             lines.append(roofline.format_row(name, art))
     return "\n".join(lines)
@@ -154,6 +163,7 @@ def write_report(out_dir: str, artifacts: dict) -> str:
 
 
 def main(argv: Optional[list] = None) -> None:
+    from repro.compile_cache import enable_compile_cache
     from repro.core.crrm import CRRM
     from repro.sim.scenarios import make_scenario, scenario_names
 
@@ -166,6 +176,7 @@ def main(argv: Optional[list] = None) -> None:
                     help="override the preset's UE count (CI shrink)")
     ap.add_argument("--out", default="artifacts/obs")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     names = args.scenario or list(scenario_names())
     arts = {}
     for name in names:

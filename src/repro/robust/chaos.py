@@ -134,6 +134,7 @@ def drill(ckpt_dir: str, n_ues: int = 64, n_cells: int = 7,
 def main(argv=None) -> None:
     import argparse
 
+    from repro.compile_cache import enable_compile_cache
     from repro.obs.telemetry import format_summary
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -143,6 +144,7 @@ def main(argv=None) -> None:
     ap.add_argument("--cells", type=int, default=7)
     ap.add_argument("--chunk", type=int, default=20)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     with tempfile.TemporaryDirectory() as td:
         kpis = drill(td, n_ues=args.ues, n_cells=args.cells,

@@ -876,36 +876,17 @@ se_jit = jax.jit(se_of)
 # ---------------------------------------------------------------------------
 # the one-call forward pass (dense backends: fused Pallas pipeline | XLA)
 # ---------------------------------------------------------------------------
-#: cached result of the one-time Pallas capability probe (None = not probed)
-_PALLAS_PROBE = None
-
-
 def pallas_available() -> bool:
-    """One-time capability probe for the fused Pallas backend.
+    """Does the default backend run the compiled fused Pallas kernel?
 
-    True iff a compiled (non-interpret) ``fused_sinr_accumulate`` builds
-    and runs on the default backend -- i.e. a real TPU (or compatible
-    Pallas lowering) is present.  On CPU containers this is False and
-    ``backend="auto"`` stays on XLA; an *explicit* ``backend="pallas"``
-    still runs there through the kernel's interpret mode (bit-faithful,
-    Python-speed -- the correctness path CI exercises).
+    True exactly on a TPU.  There the kernel always compiles for real, so
+    a configuration the compiler refuses raises to the caller instead of
+    quietly falling back to XLA.  Elsewhere ``backend="auto"`` stays on
+    XLA; an *explicit* ``backend="pallas"`` still runs through the
+    kernel's interpret mode (bit-faithful, Python-speed -- the
+    correctness path the CPU tests exercise).
     """
-    global _PALLAS_PROBE
-    if _PALLAS_PROBE is None:
-        try:
-            from repro.kernels import ops
-            if jax.default_backend() == "cpu":
-                _PALLAS_PROBE = False
-            else:
-                ops.fused_sinr(
-                    jnp.zeros((8, 3)), jnp.ones((8, 3)),
-                    jnp.ones((8, 1)),
-                    pathgain_fn=lambda d2, d3, hb, hu: 1.0 / (1.0 + d3),
-                    noise_w=1e-12, interpret=False)
-                _PALLAS_PROBE = True
-        except Exception:                      # pragma: no cover - no TPU
-            _PALLAS_PROBE = False
-    return _PALLAS_PROBE
+    return jax.default_backend() == "tpu"
 
 
 def pallas_supported(cfg: RadioConfig, fad) -> bool:
@@ -983,10 +964,10 @@ def radio_forward(static: RadioStatic, positions, fad=None,
     ``kernels/fused_sinr`` pipeline -- O(N) HBM traffic, interpret-mode
     on CPU, ``G``/``rsrp`` returned as ``None`` since they are never
     materialised, outputs within 1e-4 of XLA) or ``"auto"`` (Pallas iff
-    the capability probe and :func:`pallas_supported` both pass, else
-    XLA).  The flip is opt-in -- ``None`` never dispatches the kernel,
-    so existing callers keep materialised, bit-exact outputs on every
-    platform.  Both branches are parity-tested across every registry
+    :func:`pallas_available` (a TPU) and :func:`pallas_supported` both
+    hold, else XLA).  The flip is opt-in -- ``None`` never dispatches the
+    kernel, so existing callers keep materialised, bit-exact outputs on
+    every platform.  Both branches are parity-tested across every registry
     scenario (tests/test_kernel_vs_crrm.py).
 
     Bit-exact with the smart-update graph's node queries for the same

@@ -40,12 +40,13 @@ the existing pure episode engine (DESIGN.md §Digital-twin-serving):
   a cadence.  On NaN, exception or timeout the server recovers: if the
   failure is a genuine chunk exception (not a guard/timeout verdict)
   and a fused incremental backend is armed, it first degrades
-  ``pallas -> xla``, rebuilding the chunk program (the capability probe
-  passed but the kernel failed at runtime); it then rolls back to the
-  newest checkpoint that still validates (``restore_latest_valid`` -- a
-  corrupted latest step falls through to the previous good one), sleeps
-  an exponential backoff and retries; ``max_retries`` consecutive failures stop the server
-  gracefully with a :class:`~repro.robust.watchdog.TwinServerDown`
+  ``pallas -> xla``, rebuilding the chunk program, and logs a warning
+  that names the exception (a run that degraded is not a Pallas run);
+  it then rolls back to the newest checkpoint that still validates
+  (``restore_latest_valid`` -- a corrupted latest step falls through to
+  the previous good one), sleeps an exponential backoff and retries;
+  ``max_retries`` consecutive failures stop the server gracefully with
+  a :class:`~repro.robust.watchdog.TwinServerDown`
   carrying the full failure history.  Because every per-TTI PRNG stream
   folds on the absolute TTI counter, a recovered twin resumes *bitwise*
   on the uninterrupted trajectory (tests/test_faults.py; chaos drill:
@@ -53,6 +54,7 @@ the existing pure episode engine (DESIGN.md §Digital-twin-serving):
 """
 from __future__ import annotations
 
+import logging
 import time
 
 import jax
@@ -66,6 +68,8 @@ from repro.robust.watchdog import (GuardViolation, TwinFault,
                                    run_with_timeout)
 from repro.sim.mobility import ChurnConfig
 from repro.train import checkpoint as ckpt
+
+log = logging.getLogger(__name__)
 
 
 class TwinServer:
@@ -211,9 +215,12 @@ class TwinServer:
                     f"attempt {attempt}: {type(e).__name__}: {e}")
                 if (not isinstance(e, TwinFault)
                         and self.inc_backend in ("pallas", "auto")):
-                    # degradation ladder: the fused kernel failed outside
-                    # the capability probe -- rebuild on the XLA route
-                    # before retrying (same trajectory, slower program)
+                    # degradation ladder: the fused chunk failed -- rebuild
+                    # on the XLA route before retrying (same trajectory,
+                    # different program), and say so
+                    log.warning("twin chunk failed on inc_backend=%r "
+                                "(%s: %s); rebuilding on 'xla'",
+                                self.inc_backend, type(e).__name__, e)
                     self.fault_history.append(
                         f"degrading inc_backend={self.inc_backend!r} "
                         "-> 'xla'")
@@ -366,6 +373,7 @@ def main(argv=None) -> None:
     import argparse
     import tempfile
 
+    from repro.compile_cache import enable_compile_cache
     from repro.obs.telemetry import format_summary
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
@@ -380,6 +388,7 @@ def main(argv=None) -> None:
     ap.add_argument("--lifetime-s", type=float, default=0.4)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         with tempfile.TemporaryDirectory() as td:
