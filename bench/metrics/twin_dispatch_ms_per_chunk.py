@@ -1,0 +1,13 @@
+"""Mean time per chunk of the program's ``crrm:twin.dispatch`` span (the
+call of the jitted chunk program until it returns), over the chunks of
+the window, in ms."""
+from bench.lib.stages import window_chunks
+
+SPAN = "twin.dispatch"
+
+
+def read(run):
+    found = window_chunks(run)
+    if found is None:
+        return None
+    return sum(c.parts.get(SPAN, 0) for c in found) / len(found) / 1e6

@@ -769,20 +769,22 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         """
         n_dirty = jnp.int32(0)
         if mobility_step_m is not None:
-            d, start = walk_displacements(k_mob)
-            U = mobility.apply_walk(U, d, p.extent_m)
-            if start is None:
-                idx = jnp.arange(n_loc, dtype=jnp.int32)
-                n_dirty = jnp.int32(n_loc)
-            else:
-                idx, n_dirty = window_dirty_indices(start)
-            if inc_fused:
-                rs = radio.radio_update_rows_fused(
-                    cfg, rs, U, static.C, static.bore, fad, P, idx)
-            else:
-                rs = radio.radio_update_rows(cfg, rs, U, static.C,
-                                             static.bore, fad, P, idx,
-                                             cell_axis=cell_axes)
+            with jax.named_scope("mobility"):
+                d, start = walk_displacements(k_mob)
+                U = mobility.apply_walk(U, d, p.extent_m)
+                if start is None:
+                    idx = jnp.arange(n_loc, dtype=jnp.int32)
+                    n_dirty = jnp.int32(n_loc)
+                else:
+                    idx, n_dirty = window_dirty_indices(start)
+            with jax.named_scope("radio"):
+                if inc_fused:
+                    rs = radio.radio_update_rows_fused(
+                        cfg, rs, U, static.C, static.bore, fad, P, idx)
+                else:
+                    rs = radio.radio_update_rows(cfg, rs, U, static.C,
+                                                 static.bore, fad, P, idx,
+                                                 cell_axis=cell_axes)
         return U, rs, n_dirty
 
     def allocate(se, cqi, a, buf, avg, cursor, harq_pending, act, fair):
@@ -909,31 +911,33 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         act, fad_c, born = state.active, state.fad, None
         n_born = jnp.int32(0)
         if churn_on:
-            k_birth, k_death, k_pos, k_fadc = radio.churn_keys(key, t)
-            act, born, n_born = mobility.birth_death_step(
-                k_birth, k_death, act, tti_s, churn)
-            # departed rows idle out; reborn slots then reset fresh (a
-            # slot can depart and be re-occupied within one TTI)
-            buf = jnp.where(act, buf, 0.0)
-            avg = jnp.where(act, avg, 0.0)
-            hbits = jnp.where(act, hbits, 0.0)
-            hretx = jnp.where(act, hretx, 0)
-            ttt = jnp.where(act, ttt, 0)
-            buf = jnp.where(born, nb_backlog, buf)
-            avg = jnp.where(born, 0.0, avg)
-            hbits = jnp.where(born, 0.0, hbits)
-            hretx = jnp.where(born, 0, hretx)
-            ttt = jnp.where(born, 0, ttt)
-            born_idx = radio.dirty_indices(born, max_birth)
-            U = scatter_born(
-                U, born_idx,
-                deploy.ppp_points(k_pos, max_birth, p.extent_m, z=p.h_ut_m),
-                n_born)
-            if fad_carried:
-                fad_c = scatter_born(
-                    fad_c, born_idx,
-                    radio.draw_fading(cfg, k_fadc, max_birth, n_cells),
+            with jax.named_scope("churn"):
+                k_birth, k_death, k_pos, k_fadc = radio.churn_keys(key, t)
+                act, born, n_born = mobility.birth_death_step(
+                    k_birth, k_death, act, tti_s, churn)
+                # departed rows idle out; reborn slots then reset fresh (a
+                # slot can depart and be re-occupied within one TTI)
+                buf = jnp.where(act, buf, 0.0)
+                avg = jnp.where(act, avg, 0.0)
+                hbits = jnp.where(act, hbits, 0.0)
+                hretx = jnp.where(act, hretx, 0)
+                ttt = jnp.where(act, ttt, 0)
+                buf = jnp.where(born, nb_backlog, buf)
+                avg = jnp.where(born, 0.0, avg)
+                hbits = jnp.where(born, 0.0, hbits)
+                hretx = jnp.where(born, 0, hretx)
+                ttt = jnp.where(born, 0, ttt)
+                born_idx = radio.dirty_indices(born, max_birth)
+                U = scatter_born(
+                    U, born_idx,
+                    deploy.ppp_points(k_pos, max_birth, p.extent_m,
+                                      z=p.h_ut_m),
                     n_born)
+                if fad_carried:
+                    fad_c = scatter_born(
+                        fad_c, born_idx,
+                        radio.draw_fading(cfg, k_fadc, max_birth, n_cells),
+                        n_born)
         # -- cell faults: one Markov transition per TTI (radio.fault_keys
         # -- its own stream lineage, so fault-free trajectories are
         # bit-untouched), then the per-cell tx mask.  The draw is global
@@ -941,10 +945,11 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         # agrees across a mesh; only the P columns are local.
         cs, changed = state.cell_state, None
         if faults_on:
-            cs, changed = sim_faults.fault_step(
-                radio.fault_keys(key, t), cs, tti_s, faults)
-            pmul = sim_faults.tx_multiplier(cs, faults)
-            P = P * local_cols(pmul, axis=0)[:, None]
+            with jax.named_scope("faults"):
+                cs, changed = sim_faults.fault_step(
+                    radio.fault_keys(key, t), cs, tti_s, faults)
+                pmul = sim_faults.tx_multiplier(cs, faults)
+                P = P * local_cols(pmul, axis=0)[:, None]
         # -- channel: incremental state (carried or hoisted), per-TTI
         # recompute, or the hoisted dense constants -------------------------
         r = rs if rs is not None else h.get("rs")
@@ -952,40 +957,47 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
             f_inc = fad_c if fad_carried else inc_fad(static)
             if rs is not None:              # carried: mobility dirties rows
                 U, r, n_dirty = inc_channel(static, r, U, P, k_mob, f_inc)
-                if churn_on:
-                    # patch the newborn rows (idempotent row recompute, so
-                    # the row-0 padding of dirty_indices is safe here)
-                    r = radio.radio_update_rows(cfg, r, U, static.C,
-                                                static.bore, f_inc, P,
-                                                born_idx)
-                    n_dirty = n_dirty + n_born
-                if faults_on:
-                    # a fault transition re-prices every UE against the
-                    # masked P from the carried gains -- no geometry, no
-                    # pathloss.  Single device: a real lax.cond, so a
-                    # fault-free TTI pays only the transition draw (the
-                    # predicate is a replicated scalar; under vmap the
-                    # cond lowers to a select).  Mesh: call branch-free
-                    # (radio_update_cells where-selects internally) --
-                    # collectives inside a cond branch are avoided.
-                    def cell_upd(s):
-                        return radio.radio_update_cells(
-                            cfg, s, P, changed, cell_axis=cell_axes)
-                    if mesh is None:
-                        r = jax.lax.cond(jnp.any(changed), cell_upd,
-                                         lambda s: s, r)
-                    else:
-                        r = cell_upd(r)
+                with jax.named_scope("radio"):
+                    if churn_on:
+                        # patch the newborn rows (idempotent row
+                        # recompute, so the row-0 padding of
+                        # dirty_indices is safe here)
+                        r = radio.radio_update_rows(cfg, r, U, static.C,
+                                                    static.bore, f_inc, P,
+                                                    born_idx)
+                        n_dirty = n_dirty + n_born
+                    if faults_on:
+                        # a fault transition re-prices every UE against
+                        # the masked P from the carried gains -- no
+                        # geometry, no pathloss.  Single device: a real
+                        # lax.cond, so a fault-free TTI pays only the
+                        # transition draw (the predicate is a replicated
+                        # scalar; under vmap the cond lowers to a
+                        # select).  Mesh: call branch-free
+                        # (radio_update_cells where-selects internally)
+                        # -- collectives inside a cond branch are avoided.
+                        def cell_upd(s):
+                            return radio.radio_update_cells(
+                                cfg, s, P, changed, cell_axis=cell_axes)
+                        if mesh is None:
+                            r = jax.lax.cond(jnp.any(changed), cell_upd,
+                                             lambda s: s, r)
+                        else:
+                            r = cell_upd(r)
                 rs = r
             if ho_on:
-                if churn_on:
-                    # newborns attach instantaneously to their best cell
-                    a_srv = jnp.where(
-                        born, jnp.argmax(r.meas, axis=1).astype(a_srv.dtype),
-                        a_srv)
-                a_srv, ttt = a3_step(a_srv, ttt, r.meas)
+                with jax.named_scope("attach"):
+                    if churn_on:
+                        # newborns attach instantaneously to their best
+                        # cell
+                        a_srv = jnp.where(
+                            born,
+                            jnp.argmax(r.meas, axis=1).astype(a_srv.dtype),
+                            a_srv)
+                    a_srv, ttt = a3_step(a_srv, ttt, r.meas)
                 a_use = a_srv
-                se, cqi = gather_serving(r.se_all, r.cqi_all, a_use)
+                with jax.named_scope("link"):
+                    se, cqi = gather_serving(r.se_all, r.cqi_all, a_use)
             else:
                 se, cqi, a_use = r.se, r.cqi, r.a
         elif mobility_step_m is not None or churn_on:
@@ -994,26 +1006,33 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
             # the geometry still changes per TTI (births move rows), so
             # the full chain recomputes from the current U
             if mobility_step_m is not None:
-                d, _ = walk_displacements(k_mob)
-                U = mobility.apply_walk(U, d, p.extent_m)
-            G0 = unfaded_gain(U, static.C, static.bore)
-            fad = (draw_fading(k_fad) if per_tti_fading
-                   else (fad_c if fad_carried else static.fad))
-            R = faded_rsrp(G0, P, fad)
-            R_meas = radio.rsrp(G0, P) if attach_on_mean else R
-            a_inst = attach(R_meas)
+                with jax.named_scope("mobility"):
+                    d, _ = walk_displacements(k_mob)
+                    U = mobility.apply_walk(U, d, p.extent_m)
+            with jax.named_scope("radio"):
+                G0 = unfaded_gain(U, static.C, static.bore)
+                fad = (draw_fading(k_fad) if per_tti_fading
+                       else (fad_c if fad_carried else static.fad))
+                R = faded_rsrp(G0, P, fad)
+                R_meas = radio.rsrp(G0, P) if attach_on_mean else R
+            with jax.named_scope("attach"):
+                a_inst = attach(R_meas)
         elif per_tti_fading or power_act or faults_on:
-            fad = draw_fading(k_fad) if per_tti_fading else static.fad
-            R = faded_rsrp(h["G"], P, fad)
+            with jax.named_scope("radio"):
+                fad = draw_fading(k_fad) if per_tti_fading else static.fad
+                R = faded_rsrp(h["G"], P, fad)
             if power_act or faults_on:
                 # the fault mask (like a power action) changes P per
                 # TTI, so measurement and attachment recompute from the
                 # hoisted gain
-                R_meas = radio.rsrp(h["G"], P) if attach_on_mean else R
-                a_inst = attach(R_meas)
+                with jax.named_scope("radio"):
+                    R_meas = radio.rsrp(h["G"], P) if attach_on_mean else R
+                with jax.named_scope("attach"):
+                    a_inst = attach(R_meas)
             else:
                 R_meas = h["R_mean"] if attach_on_mean else R
-                a_inst = h["a"] if attach_on_mean else attach(R)
+                with jax.named_scope("attach"):
+                    a_inst = h["a"] if attach_on_mean else attach(R)
         else:
             R = R_meas = a_inst = None   # fully static radio chain
 
@@ -1021,25 +1040,28 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         # (the incremental branch above already resolved se/cqi/a_use)
         if r is None:
             if ho_on:
-                meas_wb = (R_meas.sum(axis=-1) if R_meas is not None
-                           else h["meas_wb"])
-                if churn_on:
-                    a_srv = jnp.where(
-                        born,
-                        jnp.argmax(meas_wb, axis=1).astype(a_srv.dtype),
-                        a_srv)
-                a_srv, ttt = a3_step(a_srv, ttt, meas_wb)
+                with jax.named_scope("attach"):
+                    meas_wb = (R_meas.sum(axis=-1) if R_meas is not None
+                               else h["meas_wb"])
+                    if churn_on:
+                        a_srv = jnp.where(
+                            born,
+                            jnp.argmax(meas_wb, axis=1).astype(a_srv.dtype),
+                            a_srv)
+                    a_srv, ttt = a3_step(a_srv, ttt, meas_wb)
                 a_use = a_srv
-                if R is not None:
-                    se, cqi, _ = sinr_chain(R, a_use, meas=meas_wb)
-                else:
-                    # static channel, evolving attachment: gather from the
-                    # hoisted all-cells SINR-chain tables
-                    se, cqi = gather_serving(h["se_all"], h["cqi_all"],
-                                             a_use)
+                with jax.named_scope("link"):
+                    if R is not None:
+                        se, cqi, _ = sinr_chain(R, a_use, meas=meas_wb)
+                    else:
+                        # static channel, evolving attachment: gather from
+                        # the hoisted all-cells SINR-chain tables
+                        se, cqi = gather_serving(h["se_all"], h["cqi_all"],
+                                                 a_use)
             elif R is not None:
-                se, cqi, a_use = sinr_chain(R, a_inst,
-                                            meas=R_meas.sum(axis=-1))
+                with jax.named_scope("link"):
+                    se, cqi, a_use = sinr_chain(R, a_inst,
+                                                meas=R_meas.sum(axis=-1))
             else:
                 se, cqi, a_use = static.se, static.cqi, static.a
         if faults_on and not ho_on:
@@ -1049,59 +1071,66 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
             a_srv = a_use
 
         # -- MAC: traffic -> grant -> HARQ -> drain ------------------------
-        arrivals = local_rows(traffic_step(k_tr, t))
-        if churn_on:
-            arrivals = jnp.where(act, arrivals, 0.0)
-        buf = buf + arrivals
-        harq_pending = (hbits > 0.0) if harq_on else \
-            jnp.zeros_like(buf, dtype=bool)
-        alloc = allocate(se, cqi, a_use, buf, avg, cursor, harq_pending,
-                         act, fair)
-        drainable = jnp.where(harq_pending, 0.0, buf)
-        tb_new = mac_sched.served_bits(
-            alloc, se, drainable, rb_bw, tti_s,
-            floor=1e-6 if relax is not None else 1e-30).sum(1)
+        with jax.named_scope("traffic"):
+            arrivals = local_rows(traffic_step(k_tr, t))
+            if churn_on:
+                arrivals = jnp.where(act, arrivals, 0.0)
+            buf = buf + arrivals
+        with jax.named_scope("harq"):
+            harq_pending = (hbits > 0.0) if harq_on else \
+                jnp.zeros_like(buf, dtype=bool)
+        with jax.named_scope("sched"):
+            alloc = allocate(se, cqi, a_use, buf, avg, cursor, harq_pending,
+                             act, fair)
+            drainable = jnp.where(harq_pending, 0.0, buf)
+            tb_new = mac_sched.served_bits(
+                alloc, se, drainable, rb_bw, tti_s,
+                floor=1e-6 if relax is not None else 1e-30).sum(1)
         hstats = None
-        if harq_on:
-            bits, _, hbits, hretx, hstats = harq_step(
-                k_harq, tb_new, hbits, hretx, alloc.sum(axis=1) > 0.0)
-        elif bler > 0.0:   # HARQ-lite: lost blocks stay queued -> retx
-            bits = tb_new * local_rows(jax.random.bernoulli(
-                k_harq, 1.0 - bler, (n_ues,))).astype(tb_new.dtype)
-        else:
-            bits = tb_new
-        # clamp: served_bits <= backlog only up to float rounding
-        if harq_on:
-            buf = jnp.maximum(buf - tb_new, 0.0)  # drain on first tx
-        else:
-            buf = jnp.maximum(buf - bits, 0.0)
-        tput = bits / tti_s
-        avg = (1.0 - beta) * avg + beta * tput
+        with jax.named_scope("harq"):
+            if harq_on:
+                bits, _, hbits, hretx, hstats = harq_step(
+                    k_harq, tb_new, hbits, hretx, alloc.sum(axis=1) > 0.0)
+            elif bler > 0.0:   # HARQ-lite: lost blocks stay queued -> retx
+                bits = tb_new * local_rows(jax.random.bernoulli(
+                    k_harq, 1.0 - bler, (n_ues,))).astype(tb_new.dtype)
+            else:
+                bits = tb_new
+        with jax.named_scope("sched"):
+            # clamp: served_bits <= backlog only up to float rounding
+            if harq_on:
+                buf = jnp.maximum(buf - tb_new, 0.0)  # drain on first tx
+            else:
+                buf = jnp.maximum(buf - bits, 0.0)
+            tput = bits / tti_s
+            avg = (1.0 - beta) * avg + beta * tput
         state = EpisodeState(U, buf, avg, cursor + rb_chunk, key,
                              hbits, hretx, a_srv, ttt, t + 1,
                              active=act, fad=fad_c, cell_state=cs)
         telem = None
         if telemetry:
-            # KPIs only from values computed above: no PRNG, no carry.
-            if hstats is None:
-                acks = (bits > 0.0).sum().astype(jnp.int32)
-                nacks = (((tb_new > 0.0) & (bits == 0.0)).sum()
-                         .astype(jnp.int32) if bler > 0.0 else jnp.int32(0))
-                hstats = (acks, nacks, jnp.int32(0), jnp.float32(0.0))
-            ho_fired = ((a_srv != prev_srv).sum().astype(jnp.int32)
-                        if ho_on else jnp.int32(0))
-            n_act = act.sum().astype(jnp.int32) if churn_on else None
-            # cells_down is computed from the *replicated* cell_state --
-            # identical on every shard, so tti_telemetry must not psum
-            # it; reattach_events is a per-UE count (psums over ue_axes)
-            n_down = ((cs == sim_faults.DOWN).sum().astype(jnp.int32)
-                      if faults_on else None)
-            reatt = ((a_srv != prev_srv).sum().astype(jnp.int32)
-                     if faults_on else None)
-            telem = tti_telemetry(n_cells, n_ues, a_use, alloc, bits, tput,
-                                  buf, hstats, ho_fired, n_dirty, ue_axes,
-                                  n_act, cells_down=n_down,
-                                  reattached=reatt)
+            with jax.named_scope("telemetry"):
+                # KPIs only from values computed above: no PRNG, no carry.
+                if hstats is None:
+                    acks = (bits > 0.0).sum().astype(jnp.int32)
+                    nacks = (((tb_new > 0.0) & (bits == 0.0)).sum()
+                             .astype(jnp.int32) if bler > 0.0
+                             else jnp.int32(0))
+                    hstats = (acks, nacks, jnp.int32(0), jnp.float32(0.0))
+                ho_fired = ((a_srv != prev_srv).sum().astype(jnp.int32)
+                            if ho_on else jnp.int32(0))
+                n_act = act.sum().astype(jnp.int32) if churn_on else None
+                # cells_down is computed from the *replicated* cell_state --
+                # identical on every shard, so tti_telemetry must not psum
+                # it; reattach_events is a per-UE count (psums over ue_axes)
+                n_down = ((cs == sim_faults.DOWN).sum().astype(jnp.int32)
+                          if faults_on else None)
+                reatt = ((a_srv != prev_srv).sum().astype(jnp.int32)
+                         if faults_on else None)
+                telem = tti_telemetry(n_cells, n_ues, a_use, alloc, bits, tput,
+                                      buf, hstats, ho_fired, n_dirty, ue_axes,
+                                      n_act, cells_down=n_down,
+                                      reattached=reatt)
         return state, tput, rs, telem
 
     def setup(static, state, action):
@@ -1113,17 +1142,19 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         (``h["rs"]``) so XLA hoists every downstream loop-invariant
         subexpression exactly as it does for the dense hoisted tables.
         """
-        h = prepare(static, state.U, action is not None)
-        rs0 = None
-        if use_rs(action is not None):
-            if static_geom and not churn_on and not faults_on:
-                h["rs"] = init_rs(static, state.U, action)
-            else:
-                pmul0 = (sim_faults.tx_multiplier(state.cell_state, faults)
-                         if faults_on else None)
-                rs0 = init_rs(static, state.U, action,
-                              fad=state.fad if fad_carried else None,
-                              pmul=pmul0)
+        with jax.named_scope("call_setup"):
+            h = prepare(static, state.U, action is not None)
+            rs0 = None
+            if use_rs(action is not None):
+                if static_geom and not churn_on and not faults_on:
+                    h["rs"] = init_rs(static, state.U, action)
+                else:
+                    pmul0 = (sim_faults.tx_multiplier(state.cell_state,
+                                                      faults)
+                             if faults_on else None)
+                    rs0 = init_rs(static, state.U, action,
+                                  fad=state.fad if fad_carried else None,
+                                  pmul=pmul0)
         return h, rs0
 
     def norm_state(state):

@@ -9,8 +9,9 @@ Three orthogonal windows into an otherwise-opaque compiled episode
   counters, A3 handover events, buffer occupancy, Jain fairness and (in
   the incremental radio mode) dirty-row counts.  A trace-time switch: off
   (the default) compiles the exact legacy program.
-* :mod:`repro.obs.profile` -- ``jax.profiler`` trace/annotation context
-  managers, a compile/retrace counter that catches unintended
+* :mod:`repro.obs.profile` -- ``jax.profiler`` trace context manager,
+  the program's ``crrm:`` host spans (``annotate``, also kept in memory:
+  ``recent_spans``), a compile/retrace counter that catches unintended
   recompilation of engine and env executables, and the per-stage
   wall-time breakdown helper the benchmark harness uses.
 * :mod:`repro.obs.report` -- AOT cost analysis of the compiled TTI step:
@@ -20,4 +21,4 @@ Three orthogonal windows into an otherwise-opaque compiled episode
 """
 from repro.obs.telemetry import Telemetry, summarize, format_summary  # noqa: F401
 from repro.obs.profile import (  # noqa: F401
-    CompileCounter, RetraceWatch, StageTimer, annotate, trace)
+    CompileCounter, RetraceWatch, StageTimer, annotate, recent_spans, trace)

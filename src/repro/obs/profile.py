@@ -1,12 +1,14 @@
 """Profiling hooks: traces, annotations, compile counters, stage timers.
 
-Four small tools, all safe on any backend (every one degrades to a no-op
-when the underlying jax facility is missing):
+Five small tools, all safe on any backend (the counters and ``trace``
+degrade to a no-op when the underlying jax facility is missing):
 
 * :func:`trace` -- context manager around ``jax.profiler.trace``: dumps a
   TensorBoard/perfetto trace of everything launched inside it;
-* :func:`annotate` -- named ``TraceAnnotation`` scope so engine phases
-  (prepare / rollout / sync) are legible inside that trace;
+* :class:`annotate` -- the program's one span helper: a named host span
+  (``crrm:<name>``, with keyword arguments stored as the event's stats)
+  in that trace, also kept in a bounded in-memory record
+  (:func:`recent_spans`) that needs no profiler;
 * :class:`CompileCounter` -- counts *XLA backend compilations* process-wide
   via the ``jax.monitoring`` event stream.  Wrapping a steady-state loop in
   one is the retrace detector: a loop that re-enters XLA per iteration is
@@ -22,9 +24,10 @@ when the underlying jax facility is missing):
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import time
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
 
@@ -75,16 +78,57 @@ def trace(log_dir: str, create_perfetto_trace: bool = False):
         yield
 
 
-def annotate(name: str):
-    """A named ``TraceAnnotation`` scope (no-op without profiler support).
+#: prefix of every span the program writes into a profiler trace, kept
+#: apart from a caller's own annotations; the names after it are a
+#: contract that readers of the trace rely on (DESIGN.md §Observability)
+SPAN_PREFIX = "crrm:"
 
-    Wrap engine phases so a :func:`trace` dump shows them as labelled
-    spans:  ``with annotate("rollout"): fns.rollout(...)``.
+
+class Span(NamedTuple):
+    """One closed :class:`annotate` span, on ``time.perf_counter_ns``."""
+    name: str          # without SPAN_PREFIX
+    start_ns: int
+    end_ns: int
+    args: Dict[str, Any]
+
+
+#: the newest closed spans, oldest first (about a minute of a twin
+#: serving 50-TTI chunks; older spans fall out)
+_SPANS: "collections.deque[Span]" = collections.deque(maxlen=1 << 15)
+
+
+class annotate:
+    """A named host span, always on: ``with annotate("twin.summary",
+    readbacks=12): ...``.
+
+    Inside a :func:`trace` it is a ``TraceAnnotation`` named
+    ``crrm:<name>`` whose keyword arguments the profiler stores as the
+    event's stats, on the clock of the device planes.  Every span is
+    also appended to a bounded in-memory record (:func:`recent_spans`).
+    Costs about a microsecond when no trace is running.
     """
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:               # pragma: no cover - no profiler backend
-        return contextlib.nullcontext()
+
+    __slots__ = ("name", "args", "_me", "_t0")
+
+    def __init__(self, name: str, **args):
+        self.name, self.args = name, args
+
+    def __enter__(self) -> "annotate":
+        self._me = jax.profiler.TraceAnnotation(SPAN_PREFIX + self.name,
+                                                **self.args)
+        self._me.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        self._me.__exit__(*exc)
+        _SPANS.append(Span(self.name, self._t0, t1, self.args))
+
+
+def recent_spans() -> List[Span]:
+    """The newest closed :class:`annotate` spans, oldest first."""
+    return list(_SPANS)
 
 
 class CompileCounter:

@@ -599,12 +599,17 @@ def radio_update_rows(cfg: RadioConfig, state: RadioState, U, C, bore,
     the scatter stays local (per-UE leaves are identical on every cell
     shard after the psums, so patched rows agree across shards).
     """
-    fad_rows = None if fad is None else fad[idx]
-    rows = _chain_rows(cfg, U[idx], C, bore, fad_rows, P,
-                       with_tables=state.se_all is not None,
-                       with_gain=state.G is not None, cell_axis=cell_axis)
-    return RadioState(*(_scatter(o, idx, n)
-                        for o, n in zip(state, rows)))
+    with jax.named_scope("gather"):
+        fad_rows = None if fad is None else fad[idx]
+        U_rows = U[idx]
+    with jax.named_scope("kernel"):
+        rows = _chain_rows(cfg, U_rows, C, bore, fad_rows, P,
+                           with_tables=state.se_all is not None,
+                           with_gain=state.G is not None,
+                           cell_axis=cell_axis)
+    with jax.named_scope("scatter"):
+        return RadioState(*(_scatter(o, idx, n)
+                            for o, n in zip(state, rows)))
 
 
 def radio_update_rows_fused(cfg: RadioConfig, state: RadioState, U, C, bore,
@@ -629,18 +634,22 @@ def radio_update_rows_fused(cfg: RadioConfig, state: RadioState, U, C, bore,
             "RadioState (a/se/cqi); handover tables (se_all) and carried "
             "gains (G) need the XLA row recompute (radio_update_rows)")
     from repro.kernels import ops
-    fad_rows = None if fad is None else fad[idx]
-    gamma, a_rows, _, _ = ops.fused_sinr(
-        U[idx], C, P, pathgain_fn=cfg.pathgain_fn, noise_w=cfg.noise_w,
-        boresight=bore, fad=fad_rows,
-        attach_on_mean=(fad_rows is not None and cfg.rayleigh_fading
-                        and cfg.attach_ignores_fading),
-        n_sectors=cfg.n_sectors, interpret=interpret)
-    se_rows, cqi_rows = se_chain(cfg, gamma)
+    with jax.named_scope("gather"):
+        fad_rows = None if fad is None else fad[idx]
+        U_rows = U[idx]
+    with jax.named_scope("kernel"):
+        gamma, a_rows, _, _ = ops.fused_sinr(
+            U_rows, C, P, pathgain_fn=cfg.pathgain_fn, noise_w=cfg.noise_w,
+            boresight=bore, fad=fad_rows,
+            attach_on_mean=(fad_rows is not None and cfg.rayleigh_fading
+                            and cfg.attach_ignores_fading),
+            n_sectors=cfg.n_sectors, interpret=interpret)
+        se_rows, cqi_rows = se_chain(cfg, gamma)
     rows = RadioState(meas=None, a=a_rows, se=se_rows, cqi=cqi_rows,
                       se_all=None, cqi_all=None, G=None, G0=None)
-    return RadioState(*(_scatter(o, idx, n)
-                        for o, n in zip(state, rows)))
+    with jax.named_scope("scatter"):
+        return RadioState(*(_scatter(o, idx, n)
+                            for o, n in zip(state, rows)))
 
 
 def radio_update_cells(cfg: RadioConfig, state: RadioState, P,

@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.mac import engine as mac_engine
+from repro.obs import profile as obs_profile
 from repro.obs import telemetry as obs_telemetry
 from repro.robust import guard as robust_guard
 from repro.robust.watchdog import (GuardViolation, TwinFault,
@@ -176,15 +177,23 @@ class TwinServer:
         (module docstring) -- raising
         :class:`~repro.robust.watchdog.TwinServerDown` only after
         ``max_retries`` consecutive recoveries also failed.
+
+        Each chunk is a ``crrm:twin.chunk`` span (``obs.profile.annotate``)
+        around spans of its parts: ``twin.dispatch``, ``twin.wait``,
+        ``twin.summary`` and, guarded, ``twin.guard`` and
+        ``twin.checkpoint``; ``readbacks`` counts the device-to-host
+        transfers of a span.
         """
-        if self.watchdog is None:
-            return self._step_chunk_raw()
-        return self._step_chunk_guarded()
+        with obs_profile.annotate("twin.chunk"):
+            if self.watchdog is None:
+                return self._step_chunk_raw()
+            return self._step_chunk_guarded()
 
     def _step_chunk_raw(self):
         gen = self._gen
-        state, tput, telem = self._chunk(
-            self.static, self.state, self.power, self.fairness)
+        with obs_profile.annotate("twin.dispatch"):
+            state, tput, telem = self._chunk(
+                self.static, self.state, self.power, self.fairness)
         if gen != self._gen:
             # a rollback superseded this attempt while it ran (it timed
             # out and was abandoned): its result must not clobber the
@@ -192,9 +201,20 @@ class TwinServer:
             raise RuntimeError("stale chunk result discarded "
                                "(superseded by a rollback)")
         self.state = state
-        kpis = obs_telemetry.summarize(telem, tti_s=self.sim.params.tti_s)
-        kpis["t"] = float(self.state.t)
-        kpis["active_ues"] = float(self.state.active.sum())
+        leaves = jax.tree_util.tree_leaves(telem)
+        # the summary's first transfer is queued behind the chunk before
+        # the host waits, as an implicit wait on it would: a wait first
+        # and then the transfer costs the chunk one more round trip
+        leaves[0].copy_to_host_async()
+        with obs_profile.annotate("twin.wait"):
+            jax.block_until_ready((state, tput, telem))
+        # one transfer per telemetry leaf, then t and the live-UE count
+        readbacks = len(leaves) + 2
+        with obs_profile.annotate("twin.summary", readbacks=readbacks):
+            kpis = obs_telemetry.summarize(telem,
+                                           tti_s=self.sim.params.tti_s)
+            kpis["t"] = float(self.state.t)
+            kpis["active_ues"] = float(self.state.active.sum())
         self.last_tput, self.last_telem = tput, telem
         return kpis
 
@@ -205,7 +225,9 @@ class TwinServer:
             try:
                 kpis = run_with_timeout(self._step_chunk_raw,
                                         wd.chunk_timeout_s)
-                if not bool(robust_guard.carry_ok(self.state)):
+                with obs_profile.annotate("twin.guard", readbacks=1):
+                    ok = bool(robust_guard.carry_ok(self.state))
+                if not ok:
                     raise GuardViolation(
                         "carry invariants violated after chunk: "
                         + "; ".join(robust_guard.carry_violations(self.state)
@@ -233,7 +255,8 @@ class TwinServer:
             else:
                 self._chunks_since_ckpt += 1
                 if self._chunks_since_ckpt >= wd.ckpt_every_chunks:
-                    self.checkpoint()
+                    with obs_profile.annotate("twin.checkpoint"):
+                        self.checkpoint()
                     self._chunks_since_ckpt = 0
                 return kpis
         raise TwinServerDown(
