@@ -169,9 +169,15 @@ def allocate_pf(active, log_w, a, n_cells, n_rb, ue_axis=None,
                 differentiable=False):
     """Weight-proportional split of the grid (log-space for stability).
 
-    Sharded (``ue_axis``): the per-cell weight maximum (the log-space
-    stabiliser) and the weight sums reduce over the UE axis with
-    ``pmax``/``psum``.  ``differentiable`` selects the plain-scatter
+    Two per-cell reductions over every UE: the weight maximum (the
+    log-space stabiliser) and the weight sum.  The maximum is exact in
+    any order, so on the TPU with few cells it lowers as a dense masked
+    reduction (``segments.segment_max``: bitwise the scatter, which the
+    TPU serialises); the float sum depends on its order and stays the
+    scatter.
+
+    Sharded (``ue_axis``): both reduce locally, then over the UE axis
+    with ``pmax``/``psum``.  ``differentiable`` selects the plain-scatter
     segment reductions (autodiff-traceable; the relaxed engine path).
     """
     # the idle sentinel: -inf is exact but poisons reverse-mode autodiff
@@ -181,9 +187,10 @@ def allocate_pf(active, log_w, a, n_cells, n_rb, ue_axis=None,
     # forward, with a clean zero gradient
     neg = _NEG if differentiable else -jnp.inf
     log_w = jnp.where(active, log_w, neg)
-    # segment reductions: unbatched these ARE the .at[a].max/.at[a].add
-    # scatters (bit-exact); under vmap their custom rule avoids the slow
-    # rank-2 batched scatter (repro.mac.segments)
+    # segment reductions, bitwise the .at[a].max/.at[a].add scatters:
+    # the max dense over few cells on the TPU, the sum a scatter whose
+    # custom vmap rule avoids the slow rank-2 batched scatter
+    # (repro.mac.segments)
     cell_max = segments.segment_max(log_w, a, n_cells, fill=neg,
                                     differentiable=differentiable)
     if ue_axis is not None:

@@ -1,4 +1,5 @@
-"""The fused Pallas kernel compiles for a TPU v5e (``interpret=False``).
+"""The fused Pallas kernel compiles for a TPU v5e (``interpret=False``),
+and so does the PF scheduler's dense per-cell maximum.
 
 No chip is needed: the TPU compiler compiles for a described, unattached
 v5e, and refuses there what the chip would refuse -- layouts Mosaic cannot
@@ -6,12 +7,16 @@ lower, gathers, VMEM overflow.  Each case is one kernel of the main path at
 its real widths (about two seconds of compile).  The topology is described
 inside a fixture only, so collecting this file never loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import fused_sinr
+from repro.mac import scheduler as mac_sched
+from repro.mac import segments
 from repro.sim.pathloss import make_pathloss
 
 
@@ -62,3 +67,35 @@ def test_wideband_fading_attach_on_mean_compiles(one_chip):
     """Wideband fading with attachment on the unfaded mean."""
     _compile(one_chip, n=1024, m=512, k=2, bn=256, bm=512, fading="wide",
              attach_on_mean=True)
+
+
+@pytest.mark.parametrize("batch,n,k", [(0, 6_250_000, 1), (128, 570, 4)],
+                         ids=["movers20", "drops128"])
+def test_pf_dense_max_compiles(one_chip, monkeypatch, batch, n, k):
+    """``allocate_pf`` at the benchmark cells' shapes (57 cells), as the
+    chip compiles it: one scatter left, the sum's, and temporaries no
+    larger than with the max's scatter (no (n, 57) buffer)."""
+    n_cells = 57
+    lead = (batch,) if batch else ()
+
+    def pf(active, log_w, a):
+        return mac_sched.allocate_pf(active, log_w, a, n_cells, 12)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(lead + shape, dtype, sharding=one_chip)
+
+    def compile_pf(dense):
+        # the described chip is not the default backend: choose for it
+        monkeypatch.setattr(segments, "_scatter_serialises", lambda: dense)
+        return jax.jit(jax.vmap(pf) if batch else pf).lower(
+            sds((n, k), jnp.bool_), sds((n, k), jnp.float32),
+            sds((n,), jnp.int32)).compile()
+
+    compiled, scattered = compile_pf(True), compile_pf(False)
+    text = compiled.as_text()
+    scatters = re.findall(r"= \S+ scatter\(.*to_apply=(%[\w.-]+)", text)
+    assert len(scatters) == 1, scatters
+    combiner = text[text.index(scatters[0] + " ("):]
+    assert " add(" in combiner[:combiner.index("\n}")]
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= scattered.memory_analysis().temp_size_in_bytes)
