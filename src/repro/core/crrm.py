@@ -16,19 +16,41 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as PSpec
 
 from repro.core import blocks
 from repro.core.graph import Graph, RootNode
 from repro.core.params import CRRM_parameters
 from repro.mac import traffic
+from repro.obs.profile import annotate
 from repro.sim import deploy, radio
 from repro.sim.antenna import Antenna_gain, sector_boresights
 from repro.sim.pathloss import make_pathloss
 
 
+def drop_ues(key, n_ues: int, extent_m: float, h_ut_m: float):
+    """The UE field of a drop: uniform over the square at UE height."""
+    xy = jax.random.uniform(key, (n_ues, 2), minval=0.0, maxval=extent_m)
+    return jnp.concatenate([xy, jnp.full((n_ues, 1), h_ut_m)], axis=1)
+
+
 class CRRM:
-    def __init__(self, params: CRRM_parameters):
+    def __init__(self, params: CRRM_parameters, mesh=None):
+        """Build the simulator for ``params``.
+
+        ``mesh`` (a ``jax.sharding.Mesh`` every axis of which shards the
+        UE dimension) builds the field straight onto the mesh for an
+        episode run with ``episode_fns(mesh=mesh)``: each shard draws
+        and keeps its own rows, computes their serving chain with
+        ``radio.radio_init`` and the PF seed with the per-cell sums
+        psummed across shards, and no device ever holds an
+        ``(n_ues, n_cells)`` array (DESIGN.md §Million-UE-scaling).
+        Such a simulator has no smart-update graph: only
+        ``episode_static``, ``init_episode_state`` and ``episode_fns``.
+        """
         self.params = params
+        self.mesh = mesh
         p = params
         key = jax.random.PRNGKey(p.seed)
         k_ue, k_cell, k_fad = jax.random.split(key, 3)
@@ -36,11 +58,10 @@ class CRRM:
         # -- topology roots -------------------------------------------------
         if p.ue_positions is not None:
             U0 = jnp.asarray(p.ue_positions, dtype=jnp.float32)
+        elif mesh is None:
+            U0 = drop_ues(k_ue, p.n_ues, p.extent_m, p.h_ut_m)
         else:
-            xy = jax.random.uniform(k_ue, (p.n_ues, 2), minval=0.0,
-                                    maxval=p.extent_m)
-            U0 = jnp.concatenate(
-                [xy, jnp.full((p.n_ues, 1), p.h_ut_m)], axis=1)
+            U0 = None                   # drawn on the mesh, shard by shard
         if p.cell_positions is not None:
             C0 = jnp.asarray(p.cell_positions, dtype=jnp.float32)
         else:
@@ -55,7 +76,7 @@ class CRRM:
                 [p.extent_m / 2, p.extent_m / 2, 0.0])
             C0 = deploy.replicate_sectors(sites, p.n_sectors)
         self.n_cells = int(C0.shape[0])
-        self.n_ues = int(U0.shape[0])
+        self.n_ues = p.n_ues if U0 is None else int(U0.shape[0])
 
         # frequency grid: n_subbands power subbands x n_rb_subbands CQI
         # subbands each; every per-frequency tensor below has trailing axis
@@ -83,6 +104,13 @@ class CRRM:
         #: consumer -- graph nodes, TTI engine, env resets -- derives from
         self._radio_cfg = radio.config_from_params(
             p, self.pathgain_function, antenna)
+
+        init_backlog, self._traffic_step = traffic.make_traffic(
+            p.traffic_model, self.n_ues, p.tti_s, **p.traffic_params)
+        if mesh is not None:
+            self._sharded = self._build_sharded(k_ue, U0, C0, P0, bore0,
+                                                init_backlog)
+            return
 
         if p.rayleigh_fading:
             F0 = radio.draw_fading(self._radio_cfg, k_fad, self.n_ues,
@@ -138,8 +166,6 @@ class CRRM:
         # -- MAC subsystem: traffic -> buffers -> scheduler -> served -------
         # The legacy ThroughputNode above is the full_buffer + fairness_p
         # special case of this chain (asserted in tests/test_mac.py).
-        init_backlog, self._traffic_step = traffic.make_traffic(
-            p.traffic_model, self.n_ues, p.tti_s, **p.traffic_params)
         self.buffer = g.add(blocks.BufferNode(init_backlog()))
         self.sched = g.add(blocks.ScheduleNode(
             self.se, self.cqi, self.a, self.buffer, self.n_cells,
@@ -267,6 +293,80 @@ class CRRM:
                                  cfg=self._radio_cfg)
 
     # ------------------------------------------------------------------ episodes
+    def _build_sharded(self, k_ue, U0, C0, P0, bore0, init_backlog):
+        """The episode's inputs built on ``self.mesh``, one span long.
+
+        Positions are the single-device draw (``drop_ues`` of the same
+        key) placed row-sharded; each shard runs ``radio.radio_init`` on
+        its rows (serving chain only, no fading) and the stationary PF
+        seed, whose per-cell maximum and sum cross shards as
+        ``pmax``/``psum``.  Returns ``(EpisodeStatic, U, backlog,
+        pf_avg)``.
+        """
+        from repro.mac import engine as mac_engine
+        p, mesh, cfg, n_cells = self.params, self.mesh, self._radio_cfg, \
+            self.n_cells
+        axes = tuple(mesh.axis_names)
+        shards = mesh.size
+        if p.rayleigh_fading:
+            raise ValueError(
+                "CRRM(mesh=...) builds the unfaded channel only: a fading "
+                "tensor is (n_ues, n_cells) per draw, which the sharded "
+                "build exists not to hold; pass rayleigh_fading=False or "
+                "build without a mesh")
+        if self.n_ues % shards:
+            raise ValueError(f"n_ues={self.n_ues} must divide evenly over "
+                             f"the {shards} shards of mesh axes {axes}")
+        rows = NamedSharding(mesh, PSpec(axes))
+        rows2 = NamedSharding(mesh, PSpec(axes, None))
+        rep = NamedSharding(mesh, PSpec())
+
+        def chain(U, backlog, C, P, bore):
+            rs = radio.radio_init(cfg, U, C, bore, None, P)
+            avg = mac_engine.stationary_served_tput(
+                p, n_cells, rs.se, rs.cqi, rs.a, backlog, ue_axis=axes)
+            return rs.se, rs.cqi, rs.a, avg
+
+        build = jax.jit(jax.shard_map(
+            chain, mesh=mesh,
+            in_specs=(PSpec(axes, None), PSpec(axes), PSpec(), PSpec(),
+                      PSpec()),
+            out_specs=(PSpec(axes, None), PSpec(axes, None), PSpec(axes),
+                       PSpec(axes)),
+            check_vma=False))
+        with annotate("build.sharded", shards=shards,
+                      rows_per_shard=self.n_ues // shards):
+            if U0 is None:
+                U0 = jax.jit(drop_ues, static_argnums=(1, 2, 3),
+                             out_shardings=rows2)(k_ue, self.n_ues,
+                                                  p.extent_m, p.h_ut_m)
+            else:
+                U0 = jax.device_put(U0, rows2)
+            backlog = jax.jit(init_backlog, out_shardings=rows)()
+            C0, P0, bore0 = jax.device_put((C0, P0, bore0), rep)
+            se, cqi, a, avg = build(U0, backlog, C0, P0, bore0)
+            jax.block_until_ready((U0, backlog, se, cqi, a, avg))
+        static = mac_engine.EpisodeStatic(se=se, cqi=cqi, a=a, C=C0, P=P0,
+                                          bore=bore0, fad=None)
+        return static, U0, backlog, avg
+
+    def _sharded_episode_state(self, key):
+        """``init_episode_state`` of a mesh-built simulator: every leaf
+        placed on the mesh (per-UE rows sharded, scalars replicated)."""
+        from repro.mac.engine import EpisodeState
+        static, U, backlog, avg = self._sharded
+        mesh = self.mesh
+        rows = NamedSharding(mesh, PSpec(tuple(mesh.axis_names)))
+        rep = NamedSharding(mesh, PSpec())
+        zeros = lambda dt: jax.jit(lambda: jnp.zeros((self.n_ues,), dt),
+                                   out_shardings=rows)()
+        scalar = lambda x: jax.device_put(jnp.asarray(x), rep)
+        return EpisodeState(
+            U=U, backlog=backlog, pf_avg=avg, rr_cursor=scalar(jnp.int32(0)),
+            key=scalar(key), harq_bits=zeros(jnp.float32),
+            harq_retx=zeros(jnp.int32), serving=static.a,
+            ttt=zeros(jnp.int32), t=scalar(jnp.int32(0)))
+
     def init_episode_state(self, key=None):
         """Gather the full episode carry as an explicit ``EpisodeState``.
 
@@ -282,6 +382,8 @@ class CRRM:
         from repro.mac.engine import EpisodeState
         if key is None:
             key = radio.episode_key(self.params.seed)
+        if self.mesh is not None:
+            return self._sharded_episode_state(key)
         n = self.n_ues
         avg0 = getattr(self, "_pf_avg", None)
         if avg0 is None:
@@ -309,15 +411,19 @@ class CRRM:
     def episode_static(self):
         """Read the per-episode radio inputs (``EpisodeStatic``) off the
         graph: cached SE/CQI/attachment plus the C/P/boresight/fading
-        roots.  Pure data -- safe to close over, jit, or vmap against."""
+        roots.  Pure data -- safe to close over, jit, or vmap against.
+        A mesh-built simulator returns its sharded build, whose ``fad``
+        is None (no fading)."""
         from repro.mac.engine import EpisodeStatic
+        if self.mesh is not None:
+            return self._sharded[0]
         return EpisodeStatic(
             se=self.get_spectral_efficiency(), cqi=self.get_CQI(),
             a=self.get_attachment(), C=self.C._data, P=self.P._data,
             bore=self.boresight._data, fad=self.fading._data)
 
     def episode_fns(self, mobility_step_m=None, per_tti_fading: bool = False,
-                    use_harq=None, mesh=None, ue_axis=("ue",),
+                    use_harq=None, mesh=None, ue_axis=None,
                     cell_axis=None, radio_mode=None,
                     mobility_move_frac=None, inc_backend=None,
                     telemetry: bool = False, churn=None, relax=None,
@@ -327,11 +433,14 @@ class CRRM:
         per trace-time switch combination.  Both are jit-compiled and
         vmap-compatible: N parallel episodes = ``vmap`` over the state
         (see ``repro.env.CrrmEnv``).  ``mesh`` shard_maps the rollout over
-        the UE axis of a device mesh (``ue_axis`` names the mesh axes) for
-        >100k-UE episodes; ``cell_axis`` additionally shards the cell
-        dimension (a UE x cell mesh) so the per-cell radio leaves scale
-        past a single device -- see DESIGN.md §Radio-fns and
-        §Million-UE-scaling.
+        the UE axis of a device mesh (``ue_axis`` names the mesh axes,
+        ``("ue",)`` by default) for >100k-UE episodes; ``cell_axis``
+        additionally shards the cell dimension (a UE x cell mesh) so the
+        per-cell radio leaves scale past a single device -- see DESIGN.md
+        §Radio-fns and §Million-UE-scaling.  A simulator built with
+        ``CRRM(params, mesh=...)`` shards over its own mesh and all of its
+        axes: ``mesh`` and ``ue_axis`` default to those, and another mesh,
+        other axes or a ``cell_axis`` are refused.
         ``radio_mode="incremental"`` recomputes only dirty UE rows of the
         radio chain inside the scan and ``mobility_move_frac`` bounds the
         per-TTI dirtiness (DESIGN.md §Smart-update-in-scan); both default
@@ -351,6 +460,20 @@ class CRRM:
         to ``params.faults``, ``0`` forces off) -- all off, the exact
         legacy program."""
         from repro.mac import engine as mac_engine
+        if isinstance(ue_axis, str):
+            ue_axis = (ue_axis,)
+        if self.mesh is not None:
+            axes = tuple(self.mesh.axis_names)
+            mesh = self.mesh if mesh is None else mesh
+            if (mesh != self.mesh or ue_axis not in (None, axes)
+                    or cell_axis is not None):
+                raise ValueError(
+                    f"this simulator was built on a mesh with axes {axes} "
+                    f"over the UE rows; its episodes run on that mesh and "
+                    f"those axes only")
+            ue_axis = axes
+        elif ue_axis is None:
+            ue_axis = ("ue",)
         return mac_engine.episode_fns_for(
             self, mobility_step_m=mobility_step_m,
             per_tti_fading=per_tti_fading, use_harq=use_harq,

@@ -235,7 +235,8 @@ def a3_handover(a, ttt, rsrp_wb, hyst_db, ttt_tti):
     return a, ttt
 
 
-def stationary_served_tput(params, n_cells: int, se, cqi, a, backlog):
+def stationary_served_tput(params, n_cells: int, se, cqi, a, backlog,
+                           ue_axis=None):
     """Pure twin of the graph's Schedule -> ServedThroughput chain.
 
     The single-shot served throughput at the stationary alpha-fair point
@@ -243,16 +244,31 @@ def stationary_served_tput(params, n_cells: int, se, cqi, a, backlog):
     the graph.  This function computes the same numbers from explicit
     arrays, so a topology-resampling env ``reset`` can seed the PF state
     inside jit/vmap without a graph (tested identical in
-    tests/test_radio_fns.py).
+    tests/test_radio_fns.py), and a mesh-built ``CRRM`` inside
+    ``shard_map`` (``ue_axis`` names the UE mesh axes: the per-cell
+    reductions cross shards).
     """
     p = params
     active = (backlog[:, None] > 0.0) & (se > 0.0)
     log_w = mac_sched.pf_log_weights_stationary(se, p.fairness_p)
     alloc = mac_sched.allocate(p.scheduler_policy, active, cqi, a, n_cells,
-                               p.rb_per_chunk, jnp.int32(0), log_w)
+                               p.rb_per_chunk, jnp.int32(0), log_w, ue_axis)
     bits = mac_sched.served_bits(alloc, se, backlog,
                                  p.subband_bandwidth_Hz / p.n_rb, p.tti_s)
     return (bits / p.tti_s).sum(axis=1)
+
+
+#: trace-time record of the incremental chain's dirty-row budget: one
+#: ``(shards, rows_per_shard)`` entry per traced program that patches
+#: mover rows (``rows_per_shard`` is the length of the padded index
+#: vector each shard recomputes and scatters every TTI)
+_ROW_BUDGETS: list = []
+
+
+def row_budgets() -> list:
+    """The ``(shards, rows_per_shard)`` entries traced so far in this
+    process, oldest first (read the entries a trace added)."""
+    return list(_ROW_BUDGETS)
 
 
 def scatter_born(dst, idx, fresh, n_born):
@@ -704,7 +720,8 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
     def inc_fad(static):
         """The fading tensor the incremental chain consumes: ``None`` on
         the unfaded channel (``G0 * ones == G0`` bitwise; eliding the
-        ones gather/multiply is pure profit on the 100k-row hot path)."""
+        ones gather/multiply is pure profit on the 100k-row hot path,
+        and a mesh-built static holds no ones at all)."""
         return static.fad if p.rayleigh_fading else None
 
     def init_rs(static, U, action, fad=None, pmul=None):
@@ -777,6 +794,7 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
                     n_dirty = jnp.int32(n_loc)
                 else:
                     idx, n_dirty = window_dirty_indices(start)
+                _ROW_BUDGETS.append((n_shards, int(idx.shape[0])))
             with jax.named_scope("radio"):
                 if inc_fused:
                     rs = radio.radio_update_rows_fused(
@@ -1212,6 +1230,13 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         se=PSpec(ue_axes, None), cqi=PSpec(ue_axes, None), a=ue,
         C=PSpec(cell_axes, None), P=PSpec(cell_axes, None),
         bore=PSpec(cell_axes), fad=fad_spec)
+
+    def specs_of(static):
+        """``static_specs`` for this static: a mesh-built (unfaded) static
+        carries ``fad=None``, and shard_map matches treedefs exactly."""
+        if static.fad is None:
+            return static_specs._replace(fad=None)
+        return static_specs
     state_specs = EpisodeState(
         U=PSpec(ue_axes, None), backlog=ue, pf_avg=ue, rr_cursor=PSpec(),
         key=PSpec(None), harq_bits=ue, harq_retx=ue, serving=ue, ttt=ue,
@@ -1301,7 +1326,7 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         extra_specs, extra_args = extra_layout(action, fairness_p)
         out_specs = ((state_specs, ue, telem_specs) if telemetry
                      else (state_specs, ue))
-        f = sharded(one, (static_specs, state_specs) + extra_specs,
+        f = sharded(one, (specs_of(static), state_specs) + extra_specs,
                     out_specs)
         return f(static, norm_state(state), *extra_args)
 
@@ -1329,7 +1354,7 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
         extra_specs, extra_args = extra_layout(action, fairness_p)
         out_specs = ((state_specs, PSpec(None, ue_axes), telem_stack_specs)
                      if telemetry else (state_specs, PSpec(None, ue_axes)))
-        f = sharded(roll, (static_specs, state_specs) + extra_specs,
+        f = sharded(roll, (specs_of(static), state_specs) + extra_specs,
                     out_specs)
         return f(static, norm_state(state), *extra_args)
 

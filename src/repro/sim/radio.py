@@ -177,7 +177,10 @@ def pathgains(cfg: RadioConfig, U, C, bore, geom=None):
 
 
 def apply_fading(G0, fad):
-    """Broadcast a fading factor onto an unfaded gain (rank-polymorphic)."""
+    """Broadcast a fading factor onto an unfaded gain (rank-polymorphic);
+    ``fad=None`` is the unfaded channel (``G0 * 1 == G0`` bitwise)."""
+    if fad is None:
+        return G0
     if fad.ndim == G0.ndim + 1:
         return G0[..., None] * fad
     return G0 * fad

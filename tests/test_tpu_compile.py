@@ -1,5 +1,6 @@
 """The fused Pallas kernel compiles for a TPU v5e (``interpret=False``),
-and so does the PF scheduler's dense per-cell maximum.
+and so do the PF scheduler's dense per-cell maximum and the UE-sharded
+incremental rollout of ``uma_mmtc_b`` on a described v5e 2x2.
 
 No chip is needed: the TPU compiler compiles for a described, unattached
 v5e, and refuses there what the chip would refuse -- layouts Mosaic cannot
@@ -7,27 +8,52 @@ lower, gathers, VMEM overflow.  Each case is one kernel of the main path at
 its real widths (about two seconds of compile).  The topology is described
 inside a fixture only, so collecting this file never loads the TPU library.
 """
+import importlib.util
+import json
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as PSpec
 
-from repro.kernels import fused_sinr
+from repro.core.crrm import CRRM
+from repro.core.params import CRRM_parameters
+from repro.kernels import fused_sinr, ops
+from repro.mac import engine, traffic
 from repro.mac import scheduler as mac_sched
 from repro.mac import segments
+from repro.sim import radio
 from repro.sim.pathloss import make_pathloss
+
+ROOT = Path(__file__).resolve().parents[1]
+#: one v5e chip's HBM
+HBM_BYTES = 16 * 2**30
+
+
+def _bench_metric(name):
+    spec = importlib.util.spec_from_file_location(
+        name, ROOT / "bench" / "metrics" / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:                 # noqa: BLE001 - no TPU compiler
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -99,3 +125,70 @@ def test_pf_dense_max_compiles(one_chip, monkeypatch, batch, n, k):
     assert " add(" in combiner[:combiner.index("\n}")]
     assert (compiled.memory_analysis().temp_size_in_bytes
             <= scattered.memory_analysis().temp_size_in_bytes)
+
+
+def test_ue_sharded_incremental_rollout_compiles_for_2x2(topo, monkeypatch):
+    """``uma_mmtc_b.mesh4``'s program: the incremental rollout of 10 TTIs
+    at 25M UEs x 57 cells on a ``("ue",)`` mesh of the four described
+    chips (6.25M rows each), as the chip compiles it: the fused kernel
+    inside ``shard_map``, and each chip's arguments, outputs and
+    temporaries within its 16 GiB."""
+    cell = json.loads((ROOT / "bench" / "workloads"
+                       / "uma_mmtc_b.mesh4.json").read_text())
+    cfg = json.loads((ROOT / "bench" / "configs" / "uma_mmtc_b.json")
+                     .read_text())["CRRM_parameters"]
+    cfg.update(cell["params"])
+    n, m = cfg["n_ues"], cfg["n_cells"]
+    p = CRRM_parameters(**cfg)
+    # the described chip is not the default backend: choose for it
+    monkeypatch.setattr(radio, "pallas_available", lambda: True)
+    monkeypatch.setattr(segments, "_scatter_serialises", lambda: True)
+    monkeypatch.setattr(ops, "_off_tpu", lambda: False)
+    mesh = Mesh(np.asarray(topo.devices), ("ue",))
+    assert mesh.size == 4
+    cfg_small = CRRM(CRRM_parameters(**dict(cfg, n_ues=8))).radio_config()
+    fns = engine.make_episode_fns(
+        p, n, m, cfg_small,
+        traffic.make_traffic(p.traffic_model, n, p.tti_s)[1],
+        mobility_step_m=p.mobility_step_m,
+        mobility_move_frac=p.mobility_move_frac, mesh=mesh,
+        **cell["driver_args"]["episode_fns"])
+
+    def rows(*shape, dt=jnp.float32):
+        spec = PSpec("ue", *([None] * (len(shape) - 1)))
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def rep(*shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, PSpec()))
+
+    i32 = jnp.int32
+    static = engine.EpisodeStatic(
+        se=rows(n, 1), cqi=rows(n, 1, dt=i32), a=rows(n, dt=i32),
+        C=rep(m, 3), P=rep(m, 1), bore=rep(m), fad=None)
+    state = engine.EpisodeState(
+        U=rows(n, 3), backlog=rows(n), pf_avg=rows(n), rr_cursor=rep(dt=i32),
+        key=rep(2, dt=jnp.uint32), harq_bits=rows(n),
+        harq_retx=rows(n, dt=i32), serving=rows(n, dt=i32),
+        ttt=rows(n, dt=i32), t=rep(dt=i32))
+    before = len(engine.row_budgets())
+    compiled = fns.rollout.lower(
+        static, state, cell["driver_args"]["chunk_tti"]).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert engine.row_budgets()[before:] == [(4, 5_000_000)]
+    # PF's per-TTI maximum and sum cross the mesh as all-reduces named
+    # after their primitives; the benchmark's collective metric finds
+    # them by opcode, under the names the trace shows (none is fused)
+    pf = {m.group(1): m.group(2) for m in re.finditer(
+        r'%([\w.\-]+) = [^\n]*? all-reduce\([^\n]*op_name="[^"]*'
+        r'/while/body/[^"]*/sched/(pmax|psum)"', text)}
+    assert sorted(pf.values()) == ["pmax", "psum"], pf
+    found = _bench_metric("mesh_collective_ms_per_tti").collective_ops(text)
+    assert set(pf) <= set(found), (pf, found)
+    assert not [n for n in found if n.startswith("fusion")], found
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert per_chip <= HBM_BYTES, per_chip
