@@ -743,65 +743,51 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
                                 f, P, with_tables=ho_on,
                                 with_gain=faults_on, cell_axis=cell_axes)
 
-    def walk_displacements(k_mob):
-        """This TTI's per-row displacement + the window start (local rows).
+    def walk(U, k_mob):
+        """This TTI's walk of the local rows: ``(U, window rows)``.
 
         ``mobility_move_frac`` set: the exact-count window-mover draw
-        (global draw, per-shard reconstruction).  Unset: the legacy
-        every-UE walk (start None = all rows dirty) -- the PR-4 stream,
-        bit-untouched.
+        (global draw, per-shard reconstruction), with the window's local
+        rows (``radio.window_rows``, this shard's contiguous block as the
+        (offset, n_loc) restriction) moved in place by position.  Unset:
+        the legacy every-UE walk (window None = all rows dirty) -- the
+        PR-4 stream, bit-untouched.
         """
         if frac_on:
             start, d = mobility.window_movers(k_mob, n_ues, n_move,
                                               mobility_step_m)
-            rows = local_offset() + jnp.arange(n_loc)
-            d_loc, _ = mobility.window_displacements(start, d, rows, n_ues)
-            return d_loc, start
-        d = local_rows(mobility.walk_steps(k_mob, n_ues, mobility_step_m))
-        return d, None
-
-    def window_dirty_indices(start):
-        """The mover window's local dirty rows, enumerated in O(n_move).
-
-        Delegates to ``radio.window_indices`` -- the shared exact-count
-        enumeration that also backs ``radio.radio_update(window=...)`` --
-        with this shard's contiguous block as the (offset, n_loc)
-        restriction.  Returns ``(idx, count)``: the padded local index
-        vector plus the number of genuinely dirty local rows (the
-        telemetry ``dirty_rows`` counter; psums to the global ``n_move``
-        under a mesh).
-        """
-        return radio.window_indices(start, n_move, n_ues,
+            win = radio.window_rows(start, n_move, n_ues,
                                     offset=local_offset(), n_loc=n_loc)
+            return mobility.walk_window(U, d, win, p.extent_m), win
+        d = local_rows(mobility.walk_steps(k_mob, n_ues, mobility_step_m))
+        return mobility.apply_walk(U, d, p.extent_m), None
 
     def inc_channel(static, rs, U, P, k_mob, fad):
         """One incremental TTI of the radio chain: move, patch, read.
 
         Only the moved rows re-run D→G→RSRP→SINR→CQI→SE
         (``radio.radio_update_rows`` -- or its fused-kernel twin under
-        ``inc_backend`` -- under THE dirtiness convention); everything
-        else is a carried value that a dense recompute would reproduce
-        bit-identically.  Returns the updated ``(U, rs)`` plus the local
-        dirty-row count (dead code unless telemetry is on).
+        ``inc_backend`` -- on the window's rows, taken and written back by
+        position); everything else is a carried value that a dense
+        recompute would reproduce bit-identically.  Returns the updated
+        ``(U, rs)`` plus the local dirty-row count (dead code unless
+        telemetry is on).
         """
         n_dirty = jnp.int32(0)
         if mobility_step_m is not None:
             with jax.named_scope("mobility"):
-                d, start = walk_displacements(k_mob)
-                U = mobility.apply_walk(U, d, p.extent_m)
-                if start is None:
-                    idx = jnp.arange(n_loc, dtype=jnp.int32)
-                    n_dirty = jnp.int32(n_loc)
-                else:
-                    idx, n_dirty = window_dirty_indices(start)
-                _ROW_BUDGETS.append((n_shards, int(idx.shape[0])))
+                U, win = walk(U, k_mob)
+                if win is None:          # every row moved: the whole block
+                    win = radio.window_rows(0, n_loc, n_loc)
+                n_dirty = win.count
+                _ROW_BUDGETS.append((n_shards, win.size))
             with jax.named_scope("radio"):
                 if inc_fused:
                     rs = radio.radio_update_rows_fused(
-                        cfg, rs, U, static.C, static.bore, fad, P, idx)
+                        cfg, rs, U, static.C, static.bore, fad, P, win)
                 else:
                     rs = radio.radio_update_rows(cfg, rs, U, static.C,
-                                                 static.bore, fad, P, idx,
+                                                 static.bore, fad, P, win,
                                                  cell_axis=cell_axes)
         return U, rs, n_dirty
 
@@ -1025,8 +1011,7 @@ def make_episode_fns(params, n_ues: int, n_cells: int,
             # the full chain recomputes from the current U
             if mobility_step_m is not None:
                 with jax.named_scope("mobility"):
-                    d, _ = walk_displacements(k_mob)
-                    U = mobility.apply_walk(U, d, p.extent_m)
+                    U, _ = walk(U, k_mob)
             with jax.named_scope("radio"):
                 G0 = unfaded_gain(U, static.C, static.bore)
                 fad = (draw_fading(k_fad) if per_tti_fading

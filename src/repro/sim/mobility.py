@@ -116,17 +116,45 @@ def window_movers(key, n: int, n_move: int, step_m: float):
 def window_displacements(start, d, rows, n: int):
     """Per-row displacement + mover mask for the window-mover convention.
 
-    ``rows`` are global UE indices (a shard passes its own block); row r
-    is a mover iff ``(r - start) mod n < n_move`` and then takes draw
-    ``d[(r - start) mod n]`` -- so every shard reconstructs exactly the
-    rows it owns from the same global draw, and a dense all-rows caller
-    gets a zero displacement for non-movers (branch-free).
+    The per-row form of :func:`walk_window`, kept as the reference its
+    slices are tested against: ``rows`` are global UE indices (a shard
+    passes its own block); row r is a mover iff ``(r - start) mod n <
+    n_move`` and then takes draw ``d[(r - start) mod n]`` -- so every
+    shard reconstructs exactly the rows it owns from the same global
+    draw, and non-movers get a zero displacement.
     """
     n_move = d.shape[0]
     j = (rows - start) % n
     moved = j < n_move
     dj = d[jnp.clip(j, 0, n_move - 1)]
     return jnp.where(moved[:, None], dj, 0.0), moved
+
+
+def walk_window(positions, d, win, extent_m: float):
+    """Walk the window movers of a block of positions, in place.
+
+    ``win`` is the block's :class:`repro.sim.radio.WindowRows` of the
+    window ``(start, d)`` of :func:`window_movers`: each run of window rows
+    is one ``dynamic_slice`` of the positions, and its draws one slice of
+    ``d`` (taken from ``d`` twice over, since a block's run may begin at
+    any slot), so the movers get exactly :func:`apply_walk` with the
+    draws of :func:`window_displacements`.  Every other row is left as it
+    is, which is bitwise what a zero displacement gives: a position lies
+    in ``[0, extent_m]`` and is never -0.0, so its clip is itself.
+    """
+    n_move = d.shape[0]
+    dd = jnp.concatenate([d, d])
+
+    def step(U, c, mask, slot0):
+        rows = jax.lax.dynamic_slice_in_dim(U, c, win.size)
+        dj = jax.lax.dynamic_slice_in_dim(dd, jnp.remainder(slot0, n_move),
+                                          win.size)
+        xy = jnp.where(mask, jnp.clip(rows[:, :2] + dj, 0.0, extent_m),
+                       rows[:, :2])
+        return jax.lax.dynamic_update_slice_in_dim(
+            U, jnp.concatenate([xy, rows[:, 2:3]], axis=1), c, 0)
+
+    return win.update(positions, step)
 
 
 def random_walk(key, positions, idx, step_m: float, extent_m: float):

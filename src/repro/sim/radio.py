@@ -418,8 +418,8 @@ def dirty_indices(mask, budget: int):
     that measured 14 ms/TTI at 100k UEs): True rows score ``n - i`` (so
     the top-k of the score IS the ascending True index set), False rows
     score 0 and their slots are rewritten to the row-0 pad.  Callers with
-    *known* dirty counts skip even this -- the window-mover regimes
-    enumerate their rows in O(n_move) via :func:`window_indices`.
+    a *known* window skip even this -- the window-mover regimes address
+    their rows by position via :func:`window_rows`.
     """
     n = mask.shape[0]
     k = min(budget, n)
@@ -433,16 +433,17 @@ def dirty_indices(mask, budget: int):
 
 
 def window_indices(start, n_move: int, n: int, *, offset=0, n_loc=None):
-    """Exact-count dirty rows of a circular mover window, in O(n_move).
+    """A circular mover window's dirty rows as an index vector.
 
-    The window movers (``sim.mobility.window_movers``) are *contiguous*
-    global indices ``[start, start + n_move) mod n``, so each of the
-    ``n_move`` window slots maps straight to a row -- no mask, no
-    compaction.  ``offset``/``n_loc`` restrict to a shard's contiguous
-    local block (global row ``g`` -> local row ``g - offset``); rows
-    outside the block pad with row 0, THE idempotent valid-index padding
-    of the dirtiness convention.  When the window covers the block
-    (``n_move >= n_loc``) every local row recomputes.
+    The index form of :func:`window_rows`, kept as the reference its
+    slices are tested against: the window movers
+    (``sim.mobility.window_movers``) are the global rows ``[start, start +
+    n_move) mod n``, and window slot ``k`` maps to row ``(start + k) mod
+    n``.  ``offset``/``n_loc`` restrict to a shard's contiguous local
+    block (global row ``g`` -> local row ``g - offset``); slots outside
+    the block pad with row 0, THE valid-index padding of the dirtiness
+    convention.  When the window covers the block (``n_move >= n_loc``)
+    every local row recomputes.
 
     Returns ``(idx, count)``: the padded local index vector plus the
     number of genuinely dirty local rows (the telemetry ``dirty_rows``
@@ -456,6 +457,152 @@ def window_indices(start, n_move: int, n: int, *, offset=0, n_loc=None):
     valid = (local >= 0) & (local < n_loc)
     return (jnp.where(valid, local, 0).astype(jnp.int32),
             valid.sum().astype(jnp.int32))
+
+
+#: trace-time tally of how the dirty-row patches addressed their rows
+_WINDOW_LOWERINGS = {"slices": 0, "indexed": 0}
+
+
+def window_lowerings() -> dict:
+    """``{"slices": n, "indexed": m}``: how many dirty-row patches traced
+    so far in this process took a known window's rows by position
+    (:class:`WindowRows`) and how many gathered and scattered through an
+    index vector (read deltas)."""
+    return dict(_WINDOW_LOWERINGS)
+
+
+class WindowRows(NamedTuple):
+    """A circular window's rows inside one contiguous block, by position.
+
+    Built by :func:`window_rows`; the slice form of :func:`window_indices`.
+    The window wraps only at global row ``n``, a block boundary, so the
+    block holds its window rows as at most two runs of consecutive rows,
+    and a slab of ``size`` rows holds them in row order: run ``(c, first,
+    stop, slot0, live)`` fills slab positions ``[first, stop)``, which are
+    block rows ``c + p`` and window slots ``slot0 + p``.  ``live`` is None
+    for a run that is always taken and, for a second run (a block that
+    holds both ends of the window), the traced condition that it is not
+    empty.  The slab's other positions are padding: they recompute row
+    0's inputs, and row 0 takes their value, as the index form's padded
+    writes give it.  With ``full`` the window covers the block and every
+    block row recomputes from its own inputs, as the index form's
+    ``arange``.  ``count`` is the window rows in the block (the index
+    form's count).
+    """
+
+    size: int          # static slab rows
+    full: bool         # static: the slab is the whole block
+    runs: tuple        # ((c, first, stop, slot0, live), ...)
+    pad: Any           # (padded, position, at_edge) | None: no padding
+    count: Any         # i32 window rows in the block
+
+    def _mask(self, first, stop, ndim):
+        p = jnp.arange(self.size, dtype=jnp.int32)
+        m = (p >= first) & (p < stop)
+        return m.reshape((self.size,) + (1,) * (ndim - 1))
+
+    def update(self, x, fn):
+        """``x`` after ``fn(x, c, mask, slot0)`` for each run (a live run
+        under ``lax.cond``): ``fn`` rewrites the slab at block row ``c``,
+        ``mask`` its window positions."""
+        for c, first, stop, slot0, live in self.runs:
+            def one(y, c=c, first=first, stop=stop, slot0=slot0):
+                return fn(y, c, self._mask(first, stop, y.ndim), slot0)
+            x = one(x) if live is None else jax.lax.cond(
+                live, one, lambda y: y, x)
+        return x
+
+    def take(self, x):
+        """The slab of ``x`` the row chain recomputes: the window's rows by
+        position, row 0 at the padding.
+
+        A 2-D ``x`` is taken column by column: the TPU stores a narrow
+        field such as the positions column-major and the kernel reads a
+        row-major slab, and the one-dimensional columns between them let
+        each keep its layout, with one copy into the slab."""
+        if self.full:
+            return x
+        if x.ndim == 2:
+            return jnp.stack([self.take(x[:, j])
+                              for j in range(x.shape[1])], axis=1)
+        slab = jnp.broadcast_to(x[0], (self.size,) + x.shape[1:])
+        for c, first, stop, _, _ in self.runs:
+            rows = jax.lax.dynamic_slice_in_dim(x, c, self.size)
+            slab = jnp.where(self._mask(first, stop, x.ndim), rows, slab)
+        return slab
+
+    def put(self, x, rows):
+        """``x`` with the recomputed slab ``rows`` written back in place:
+        the window's rows, then row 0 from the padding."""
+        if self.full:
+            return rows
+
+        def write(y, c, mask, _):
+            old = jax.lax.dynamic_slice_in_dim(y, c, self.size)
+            return jax.lax.dynamic_update_slice_in_dim(
+                y, jnp.where(mask, rows, old), c, 0)
+
+        x = self.update(x, write)
+        if self.pad is None:
+            return x
+        padded, pos, at_edge = self.pad
+        if at_edge:                      # a static slice of the kernel's out
+            row = jnp.where(pos == 0, rows[:1], rows[-1:])
+        else:
+            row = jax.lax.dynamic_slice_in_dim(rows, pos, 1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            x, jnp.where(padded, row, x[:1]), 0, 0)
+
+
+def window_rows(start, n_win: int, n: int, *, offset=0, n_loc=None,
+                size=None) -> WindowRows:
+    """The circular window ``[start, start + n_win) mod n`` inside the
+    block of ``n_loc`` rows at global row ``offset``, by position.
+
+    ``size`` is the slab's static row count, ``min(n_win, n_loc)`` by
+    default (the index form's budget) and at least that otherwise.  The
+    window's rows of the block form one run, or two where the block holds
+    both ends of the window: the second is possible only when ``n_win > n
+    - n_loc``, which a block of a sharded field meets only when the window
+    covers it, so a shard takes one slice per pass.  A single run leaves
+    its padding at one edge of the slab.  Built from scalars in O(1);
+    :class:`WindowRows` takes and writes the rows with ``dynamic_slice``
+    and ``dynamic_update_slice``.
+    """
+    n_loc = n if n_loc is None else n_loc
+    size = min(n_win, n_loc) if size is None else size
+    if not min(n_win, n_loc) <= size <= n_loc:
+        raise ValueError(f"slab size {size} must lie in "
+                         f"[{min(n_win, n_loc)}, {n_loc}]")
+    i32 = jnp.int32
+    zero = jnp.zeros((), i32)
+    delta = jnp.remainder(jnp.asarray(start, i32) - offset, n)
+    hi = delta < n_loc                   # the window starts in the block
+    lo_len = jnp.clip(delta + n_win - n, 0, n_loc)   # its wrapped end
+    # the first run: from the window's start, else its wrapped end
+    c = jnp.where(hi, jnp.clip(delta, 0, n_loc - size), 0)
+    first = jnp.where(hi, delta - c, 0)
+    stop = jnp.where(hi, jnp.minimum(delta + n_win, n_loc) - c, lo_len)
+    runs = [(c, first, stop, jnp.where(hi, c - delta, n - delta), None)]
+    count = jnp.maximum(stop - first, 0)
+    two = n_win > n - n_loc              # both ends can lie in the block
+    if two:
+        live = hi & (lo_len > 0)
+        lo_end = jnp.where(live, lo_len, 0)
+        runs.append((zero, zero, lo_end, n - delta, live))
+        count = count + lo_end
+    if n_win >= n_loc:
+        full, pad, count = True, None, jnp.asarray(n_loc, i32)
+    elif two:                            # one block: the window fills it
+        full = False                     # but for the slab's spare rows
+        pad = None if size == n_win else (
+            jnp.asarray(True), jnp.where(lo_end < first, lo_end, stop),
+            False)
+    else:
+        full = False
+        pad = (count < size, jnp.where(first > 0, 0, size - 1), True)
+    return WindowRows(size=size, full=full, runs=tuple(runs), pad=pad,
+                      count=count.astype(i32))
 
 
 # ---------------------------------------------------------------------------
@@ -584,8 +731,22 @@ def radio_init(cfg: RadioConfig, U, C, bore, fad, P, *,
                        with_gain=with_gain, cell_axis=cell_axis)
 
 
+def _gather(x, idx):
+    """Rows ``idx`` of ``x``: a window's slab by position, else a gather."""
+    return idx.take(x) if isinstance(idx, WindowRows) else x[idx]
+
+
 def _scatter(old, idx, new_rows):
-    return None if old is None else old.at[idx].set(new_rows)
+    if old is None:
+        return None
+    if isinstance(idx, WindowRows):
+        return idx.put(old, new_rows)
+    return old.at[idx].set(new_rows)
+
+
+def _tally(idx):
+    _WINDOW_LOWERINGS["slices" if isinstance(idx, WindowRows)
+                      else "indexed"] += 1
 
 
 def radio_update_rows(cfg: RadioConfig, state: RadioState, U, C, bore,
@@ -598,13 +759,17 @@ def radio_update_rows(cfg: RadioConfig, state: RadioState, U, C, bore,
     validity mask is needed.  Cost is O(|idx| * n_cell) instead of the
     dense O(n_ue * n_cell) -- the smart-update win, inside jit.
     ``fad=None`` selects the unfaded chain (no gather, no multiply).
+    ``idx`` may instead be a :class:`WindowRows`: the rows of a known
+    circular window, taken and written back by position (the same rows,
+    values and padding, with no per-row addressing).
     ``cell_axis`` shards the cell dimension (see :func:`_chain_rows`);
     the scatter stays local (per-UE leaves are identical on every cell
     shard after the psums, so patched rows agree across shards).
     """
+    _tally(idx)
     with jax.named_scope("gather"):
-        fad_rows = None if fad is None else fad[idx]
-        U_rows = U[idx]
+        fad_rows = None if fad is None else _gather(fad, idx)
+        U_rows = _gather(U, idx)
     with jax.named_scope("kernel"):
         rows = _chain_rows(cfg, U_rows, C, bore, fad_rows, P,
                            with_tables=state.se_all is not None,
@@ -619,14 +784,16 @@ def radio_update_rows_fused(cfg: RadioConfig, state: RadioState, U, C, bore,
                             fad, P, idx, *, interpret=None) -> RadioState:
     """:func:`radio_update_rows` through the fused Pallas pipeline.
 
-    The dirty-row kernel variant: gather the dirty UE slab (positions +
-    fading rows) with XLA, stream it through ``kernels.ops.fused_sinr``
-    (gain recomputed inside VMEM tiles against *all* cells -- the
-    (|idx|, n_cell) matrices never touch HBM), scatter the patched
-    a/se/cqi rows back.  Covers the O(n_ue)-carry regimes only: handover
-    tables (``se_all``) and carried gains (``G``) need O(n_cell)-per-row
-    outputs the streaming accumulator never materialises, so those
-    regimes raise and stay on the XLA row recompute.  Same dirtiness
+    The dirty-row kernel variant: take the dirty UE slab (positions +
+    fading rows; a window's slices or an index gather, as in
+    :func:`radio_update_rows`) with XLA, stream it through
+    ``kernels.ops.fused_sinr`` (gain recomputed inside VMEM tiles against
+    *all* cells -- the (|idx|, n_cell) matrices never touch HBM), write
+    the patched a/se/cqi rows back.  Covers the O(n_ue)-carry regimes
+    only: handover tables (``se_all``) and carried gains (``G``) need
+    O(n_cell)-per-row outputs the streaming accumulator never
+    materialises, so those regimes raise and stay on the XLA row
+    recompute.  Same dirtiness
     convention, same idempotent padded scatter; parity vs the XLA rows
     is asserted across every registry scenario in
     tests/test_smart_update_scan.py.
@@ -637,9 +804,10 @@ def radio_update_rows_fused(cfg: RadioConfig, state: RadioState, U, C, bore,
             "RadioState (a/se/cqi); handover tables (se_all) and carried "
             "gains (G) need the XLA row recompute (radio_update_rows)")
     from repro.kernels import ops
+    _tally(idx)
     with jax.named_scope("gather"):
-        fad_rows = None if fad is None else fad[idx]
-        U_rows = U[idx]
+        fad_rows = None if fad is None else _gather(fad, idx)
+        U_rows = _gather(U, idx)
     with jax.named_scope("kernel"):
         gamma, a_rows, _, _ = ops.fused_sinr(
             U_rows, C, P, pathgain_fn=cfg.pathgain_fn, noise_w=cfg.noise_w,
@@ -739,10 +907,11 @@ def radio_update(static: RadioStatic, state: RadioState, U,
 
     ``window=(start, n)`` declares the dirty rows to be the circular
     index window ``[start, start + n) mod n_ue`` (the window-mover
-    mobility regime): the index vector is then *enumerated* in O(n)
-    (:func:`window_indices`) instead of compacted from the mask, and
-    ``dirty_ue_mask`` may be ``None``.  ``budget`` still bounds the
-    vector (``n <= budget`` is required).
+    mobility regime): its rows are then taken and written back by
+    position (:func:`window_rows`, a slab of ``min(budget, n_ue)`` rows)
+    instead of compacted from the mask, and ``dirty_ue_mask`` may be
+    ``None``.  ``budget`` still bounds the window (``n <= budget`` is
+    required).
     """
     cfg = static.cfg
     P = static.P if P is None else P
@@ -750,10 +919,8 @@ def radio_update(static: RadioStatic, state: RadioState, U,
         start, n_win = window
         if n_win > budget:
             raise ValueError(f"window size {n_win} exceeds budget {budget}")
-        idx, _ = window_indices(start, n_win, U.shape[0])
-        if n_win < budget:               # same static shape as the mask path
-            idx = jnp.concatenate(
-                [idx, jnp.zeros((budget - n_win,), jnp.int32)])
+        n = U.shape[0]
+        idx = window_rows(start, n_win, n, size=min(budget, n))
     else:
         idx = dirty_indices(dirty_ue_mask, budget)
     state = radio_update_rows(cfg, state, U, static.C, static.bore,

@@ -96,6 +96,28 @@ for backend, (t, serving) in outs.items():
          tput_rel=float(np.abs(t - ref[0]).max() / np.abs(ref[0]).max()),
          serving=bool(np.array_equal(serving, ref[1])))
 
+# the mover window by slices against its index form (tests/window_oracle),
+# bitwise, with the window inside each block's slab (0.2: 51 rows of 64)
+# and covering blocks (0.5), through the XLA rows and the fused kernel
+sys.path.insert(0, "tests")
+from window_oracle import index_path
+from repro.sim import radio
+sl, orc = (CRRM(CRRM_parameters(**cfg), mesh=mesh) for _ in range(2))
+for frac in (0.2, 0.5):
+    for backend in ("xla", "pallas"):
+        fkw = dict(kw, mobility_move_frac=frac, inc_backend=backend)
+        before = radio.window_lowerings()["slices"]
+        s, t = sl.episode_fns(**fkw).rollout(
+            sl.episode_static(), sl.init_episode_state(key), 4)
+        slices = radio.window_lowerings()["slices"] - before
+        with index_path():
+            so, to = orc.episode_fns(**fkw).rollout(
+                orc.episode_static(), orc.init_episode_state(key), 4)
+        emit("window", frac=frac, backend=backend, slices=slices,
+             tput=bool(np.array_equal(np.asarray(t), np.asarray(to))),
+             state=[f for f, x, y in zip(s._fields, s, so)
+                    if not np.array_equal(np.asarray(x), np.asarray(y))])
+
 # the benchmark's cell on four shards, checked against the reference;
 # after each timed call, whether the kept state shares a buffer with the
 # running one (the device's bytes would then depend on the draw)
@@ -192,6 +214,19 @@ def test_row_budget_counter_reads_shards_times_window(results):
     block, 128 cover it."""
     got = {r["frac"]: r["got"] for r in results["budget"]}
     assert got == {0.2: [[4, 51]], 0.5: [[4, 64]]}
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("frac", [0.2, 0.5])
+def test_mesh_window_rollout_matches_index_path(results, frac, backend):
+    """On four shards the rollout takes the mover window by slices and
+    ends bitwise where the index path ends: outputs and every state
+    leaf."""
+    (r,) = [r for r in results["window"]
+            if r["frac"] == frac and r["backend"] == backend]
+    assert r["slices"] >= 1
+    assert r["tput"] is True
+    assert r["state"] == []
 
 
 @pytest.mark.parametrize("backend", ["pallas", None],

@@ -2,9 +2,11 @@
 TTI engine -- dense-vs-incremental equivalence across registry scenarios,
 under vmap and on a 2-device mesh, the shared dirtiness convention, and
 the window-mover mobility regime."""
+import json
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +16,8 @@ import pytest
 from repro.core.crrm import CRRM
 from repro.core.params import CRRM_parameters
 from repro.sim import mobility, radio, scenarios
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def _shrink(name, **kw):
@@ -218,6 +222,117 @@ def test_window_movers_exact_count_and_bounds():
     disp, mask = mobility.window_displacements(start, d, rows, 40)
     assert int(mask.sum()) == 8
     np.testing.assert_array_equal(np.asarray(disp[~np.asarray(mask)]), 0.0)
+
+
+# ------------------------------------ the mover window's rows, by position
+# (n_move, shards, start) on a 24-row field: one block, with the window at
+# 0, the middle, n - n_move, one past it (wrapping) and n - 1, or covering
+# it; four blocks of 6, each missed, entered, left or covered, and padded
+# with row 0 wherever the window leaves slots of a block's slab empty
+ROW_CASES = ([(5, 1, s) for s in (0, 12, 19, 20, 23)]
+             + [(24, 1, 7), (4, 4, 0), (4, 4, 4), (4, 4, 22), (8, 4, 3),
+                (8, 4, 20)])
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["xla", "fused"])
+@pytest.mark.parametrize("n_move,shards,start", ROW_CASES)
+def test_window_rows_patch_matches_index_patch(n_move, shards, start,
+                                               fused):
+    """A window's rows taken and written back by slices
+    (``radio.window_rows``) patch every block's a/se/cqi bitwise as the
+    index form does (``window_indices`` + gather + ``.at[idx].set``), from
+    the same slab of rows (row 0 at the padding).  Each block's row 0 is
+    moved too, so its carried value is stale unless the padding rewrites
+    it, as the index form's padded writes do."""
+    sim = CRRM(_shrink("dense_urban"))
+    rs = sim.radio_static()
+    U, fad = sim.U._data, sim.fading._data
+    n = U.shape[0]
+    n_loc = n // shards
+    st = radio.radio_init(rs.cfg, U, rs.C, rs.bore, fad, rs.P)
+    moved = (start + np.arange(n_move)) % n
+    U2 = (U.at[moved].add(jnp.array([35.0, -20.0, 0.0], U.dtype))
+          .at[::n_loc].add(jnp.array([400.0, 250.0, 0.0], U.dtype)))
+    patch = (radio.radio_update_rows_fused if fused
+             else radio.radio_update_rows)
+    for s in range(shards):
+        blk = slice(s * n_loc, (s + 1) * n_loc)
+        st_b = radio.RadioState(*(None if x is None else x[blk] for x in st))
+        idx, count = radio.window_indices(jnp.int32(start), n_move, n,
+                                          offset=s * n_loc, n_loc=n_loc)
+        win = radio.window_rows(jnp.int32(start), n_move, n,
+                                offset=s * n_loc, n_loc=n_loc)
+        assert win.size == idx.shape[0] and int(win.count) == int(count)
+        slab = np.asarray(win.take(U2[blk]))
+        assert sorted(map(tuple, slab)) == sorted(
+            map(tuple, np.asarray(U2[blk][idx])))
+        want = patch(rs.cfg, st_b, U2[blk], rs.C, rs.bore, fad[blk], rs.P,
+                     idx)
+        got = patch(rs.cfg, st_b, U2[blk], rs.C, rs.bore, fad[blk], rs.P,
+                    win)
+        for field, w, g in zip(radio.RadioState._fields, want, got):
+            assert (w is None) == (g is None), field
+            if w is not None:
+                np.testing.assert_array_equal(
+                    np.asarray(g), np.asarray(w),
+                    err_msg=f"{field}, block {s}")
+
+
+def _mmtc_params(**kw):
+    """``uma_mmtc.movers20``'s deployment at a test size."""
+    cfg = json.loads((ROOT / "bench" / "configs" / "uma_mmtc.json")
+                     .read_text())["CRRM_parameters"]
+    cfg.update(n_ues=200, traffic_model="full_buffer", seed=5, **kw)
+    return CRRM_parameters(**cfg)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("frac", [0.2, 0.8])
+def test_window_rollout_matches_index_path(frac, backend):
+    """``movers20``'s incremental rollout, through the XLA rows or the
+    fused kernel (interpret mode), takes the mover window by slices and
+    ends bitwise where the index path (``window_oracle``) ends: outputs and
+    every leaf of the final state.  At 80% movers most TTIs wrap the
+    window around the field's end."""
+    from window_oracle import index_path
+    p = _mmtc_params(mobility_step_m=20.0, mobility_move_frac=frac)
+    a, b = _pair(p)
+    kw = dict(radio_mode="incremental", inc_backend=backend)
+    key = jax.random.PRNGKey(9)
+    before = radio.window_lowerings()
+    s1, t1 = a.episode_fns(**kw).rollout(a.episode_static(),
+                                         a.init_episode_state(key), 10)
+    assert radio.window_lowerings()["slices"] > before["slices"]
+    with index_path():
+        s2, t2 = b.episode_fns(**kw).rollout(b.episode_static(),
+                                             b.init_episode_state(key), 10)
+    np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
+    for field, x, y in zip(s1._fields, s1, s2):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=field)
+
+
+def test_mask_and_churn_patches_stay_indexed():
+    """Dirty rows from a mask (``radio_update`` without a window) and
+    churn births keep the index gather and scatter: no slices."""
+    from repro.sim.mobility import ChurnConfig
+    sim = CRRM(_shrink("dense_urban"))
+    rs = sim.radio_static()
+    U = sim.U._data
+    st = radio.radio_init(rs.cfg, U, rs.C, rs.bore, None, rs.P)
+    before = radio.window_lowerings()
+    radio.radio_update(rs, st, U, jnp.arange(U.shape[0]) % 5 == 0,
+                       budget=6)
+    churn = ChurnConfig(arrival_rate_hz=400.0, mean_lifetime_s=0.1,
+                        max_arrivals_per_tti=4)
+    fns = sim.episode_fns(radio_mode="incremental", churn=churn)
+    from repro.mac.engine import seed_churn_state
+    state = seed_churn_state(sim.init_episode_state(jax.random.PRNGKey(1)),
+                             sim.episode_static(), sim.params)
+    fns.rollout.lower(sim.episode_static(), state, 3)
+    after = radio.window_lowerings()
+    assert after["slices"] == before["slices"]
+    assert after["indexed"] >= before["indexed"] + 2
 
 
 # ---------------------------------------- fused dirty-row backend (ISSUE 9)

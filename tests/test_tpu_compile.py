@@ -1,6 +1,7 @@
 """The fused Pallas kernel compiles for a TPU v5e (``interpret=False``),
-and so do the PF scheduler's dense per-cell maximum and the UE-sharded
-incremental rollout of ``uma_mmtc_b`` on a described v5e 2x2.
+and so do the PF scheduler's dense per-cell maximum, the incremental
+rollout of ``uma_mmtc`` on one described v5e and the UE-sharded one of
+``uma_mmtc_b`` on a described v5e 2x2.
 
 No chip is needed: the TPU compiler compiles for a described, unattached
 v5e, and refuses there what the chip would refuse -- layouts Mosaic cannot
@@ -127,15 +128,25 @@ def test_pf_dense_max_compiles(one_chip, monkeypatch, batch, n, k):
             <= scattered.memory_analysis().temp_size_in_bytes)
 
 
-def test_ue_sharded_incremental_rollout_compiles_for_2x2(topo, monkeypatch):
-    """``uma_mmtc_b.mesh4``'s program: the incremental rollout of 10 TTIs
-    at 25M UEs x 57 cells on a ``("ue",)`` mesh of the four described
-    chips (6.25M rows each), as the chip compiles it: the fused kernel
-    inside ``shard_map``, and each chip's arguments, outputs and
-    temporaries within its 16 GiB."""
-    cell = json.loads((ROOT / "bench" / "workloads"
-                       / "uma_mmtc_b.mesh4.json").read_text())
-    cfg = json.loads((ROOT / "bench" / "configs" / "uma_mmtc_b.json")
+#: the engine's stages that move and patch the mover window
+_WINDOW_SCOPES = re.compile(r"/(mobility|radio/gather|radio/scatter)/")
+
+
+def _window_gathers(text):
+    """Gather and scatter instructions under the window's stages."""
+    return [m.group(0)[:160] for m in re.finditer(
+        r'= (?:\([^)]*\)|\S+) (?:gather|scatter)\([^\n]*?op_name="([^"]*)"',
+        text) if _WINDOW_SCOPES.search(m.group(1))]
+
+
+def _compile_rollout(topo, monkeypatch, workload, mesh=None):
+    """The compiled incremental rollout of a benchmark cell at its full
+    size on the described chips (shapes only), as the chip compiles it:
+    a ``("ue",)`` mesh shards every per-UE leaf, else one chip holds the
+    field and the graph-built static's fading root."""
+    cell = json.loads((ROOT / "bench" / "workloads" / f"{workload}.json")
+                      .read_text())
+    cfg = json.loads((ROOT / "bench" / "configs" / f"{cell['config']}.json")
                      .read_text())["CRRM_parameters"]
     cfg.update(cell["params"])
     n, m = cfg["n_ues"], cfg["n_cells"]
@@ -144,8 +155,6 @@ def test_ue_sharded_incremental_rollout_compiles_for_2x2(topo, monkeypatch):
     monkeypatch.setattr(radio, "pallas_available", lambda: True)
     monkeypatch.setattr(segments, "_scatter_serialises", lambda: True)
     monkeypatch.setattr(ops, "_off_tpu", lambda: False)
-    mesh = Mesh(np.asarray(topo.devices), ("ue",))
-    assert mesh.size == 4
     cfg_small = CRRM(CRRM_parameters(**dict(cfg, n_ues=8))).radio_config()
     fns = engine.make_episode_fns(
         p, n, m, cfg_small,
@@ -153,31 +162,73 @@ def test_ue_sharded_incremental_rollout_compiles_for_2x2(topo, monkeypatch):
         mobility_step_m=p.mobility_step_m,
         mobility_move_frac=p.mobility_move_frac, mesh=mesh,
         **cell["driver_args"]["episode_fns"])
+    one = SingleDeviceSharding(topo.devices[0])
 
     def rows(*shape, dt=jnp.float32):
+        if mesh is None:
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one)
         spec = PSpec("ue", *([None] * (len(shape) - 1)))
         return jax.ShapeDtypeStruct(shape, dt,
                                     sharding=NamedSharding(mesh, spec))
 
     def rep(*shape, dt=jnp.float32):
+        if mesh is None:
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one)
         return jax.ShapeDtypeStruct(shape, dt,
                                     sharding=NamedSharding(mesh, PSpec()))
 
     i32 = jnp.int32
     static = engine.EpisodeStatic(
         se=rows(n, 1), cqi=rows(n, 1, dt=i32), a=rows(n, dt=i32),
-        C=rep(m, 3), P=rep(m, 1), bore=rep(m), fad=None)
+        C=rep(m, 3), P=rep(m, 1), bore=rep(m),
+        fad=None if mesh is not None else rows(n, m))
     state = engine.EpisodeState(
         U=rows(n, 3), backlog=rows(n), pf_avg=rows(n), rr_cursor=rep(dt=i32),
         key=rep(2, dt=jnp.uint32), harq_bits=rows(n),
         harq_retx=rows(n, dt=i32), serving=rows(n, dt=i32),
         ttt=rows(n, dt=i32), t=rep(dt=i32))
-    before = len(engine.row_budgets())
-    compiled = fns.rollout.lower(
+    return fns.rollout.lower(
         static, state, cell["driver_args"]["chunk_tti"]).compile()
+
+
+def _per_chip_bytes(compiled):
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def test_incremental_rollout_compiles_for_one_chip(topo, monkeypatch):
+    """``uma_mmtc.movers20``'s program: the incremental rollout of 10 TTIs
+    at 6.25M UEs x 57 cells on one described chip, as the chip compiles
+    it: the fused kernel, the mover window moved and patched with slices
+    (no gather or scatter under ``mobility``, ``radio/gather`` or
+    ``radio/scatter``; PF's sum scatter under ``sched`` stays), and the
+    arguments, outputs and temporaries within the chip's 16 GiB."""
+    before = radio.window_lowerings()
+    compiled = _compile_rollout(topo, monkeypatch, "uma_mmtc.movers20")
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert radio.window_lowerings()["slices"] == before["slices"] + 1
+    assert _window_gathers(text) == []
+    assert re.search(r'scatter\([^\n]*op_name="[^"]*/sched/', text)
+    assert _per_chip_bytes(compiled) <= HBM_BYTES, _per_chip_bytes(compiled)
+
+
+def test_ue_sharded_incremental_rollout_compiles_for_2x2(topo, monkeypatch):
+    """``uma_mmtc_b.mesh4``'s program: the incremental rollout of 10 TTIs
+    at 25M UEs x 57 cells on a ``("ue",)`` mesh of the four described
+    chips (6.25M rows each), as the chip compiles it: the fused kernel
+    inside ``shard_map``, the mover window moved and patched with slices,
+    and each chip's arguments, outputs and temporaries within its 16
+    GiB."""
+    mesh = Mesh(np.asarray(topo.devices), ("ue",))
+    assert mesh.size == 4
+    before = len(engine.row_budgets())
+    compiled = _compile_rollout(topo, monkeypatch, "uma_mmtc_b.mesh4", mesh)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert engine.row_budgets()[before:] == [(4, 5_000_000)]
+    assert _window_gathers(text) == []
     # PF's per-TTI maximum and sum cross the mesh as all-reduces named
     # after their primitives; the benchmark's collective metric finds
     # them by opcode, under the names the trace shows (none is fused)
@@ -188,7 +239,4 @@ def test_ue_sharded_incremental_rollout_compiles_for_2x2(topo, monkeypatch):
     found = _bench_metric("mesh_collective_ms_per_tti").collective_ops(text)
     assert set(pf) <= set(found), (pf, found)
     assert not [n for n in found if n.startswith("fusion")], found
-    mem = compiled.memory_analysis()
-    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-                + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert per_chip <= HBM_BYTES, per_chip
+    assert _per_chip_bytes(compiled) <= HBM_BYTES, _per_chip_bytes(compiled)
