@@ -82,23 +82,13 @@ class Roofline:
         return self.model_flops / cap if cap > 0 else 0.0
 
 
-def model_flops_train(n_params_active: float, tokens: float) -> float:
-    return 6.0 * n_params_active * tokens
-
-
-def model_flops_decode(n_params_active: float, tokens: float) -> float:
-    return 2.0 * n_params_active * tokens
-
-
 def from_artifact(art: dict) -> Roofline:
-    """Prefers the analytic executed-FLOPs/bytes model (exact per-layer
-    formulas; XLA cost_analysis counts loop bodies once -- see
-    analysis/flops.py) and falls back to raw cost_analysis numbers.  The
-    peaks are those of ``art["device_kind"]`` (:func:`peaks`)."""
+    """The roofline of one compiled-program artifact from its
+    cost_analysis numbers; the peaks are those of ``art["device_kind"]``
+    (:func:`peaks`)."""
     pk = peaks(art["device_kind"])
     chips = art["n_devices"]
-    flops = art.get("analytic_flops") or art["hlo_flops"]
-    bytes_ = art.get("analytic_bytes") or art["hlo_bytes"]
+    flops, bytes_ = art["hlo_flops"], art["hlo_bytes"]
     return Roofline(
         compute_s=flops / (chips * pk.flops),
         memory_s=bytes_ / (chips * pk.hbm_bw),

@@ -7,13 +7,10 @@ the kernel-accumulated O(N) state.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import fused_sinr as _fused
-from repro.kernels import pairwise_dist as _dist
 
 
 def _off_tpu() -> bool:
@@ -34,19 +31,6 @@ def _pad_axis(x, mult, axis=0, fill=0.0):
 
 def _pad_rows(x, mult, fill=0.0):
     return _pad_axis(x, mult, axis=0, fill=fill)
-
-
-def pairwise_dist(U, C, *, bn: int = 256, bm: int = 512, interpret=None):
-    """(d2d, d3d) via the tiled MXU kernel; pads then slices."""
-    if interpret is None:
-        interpret = _off_tpu()
-    n, m = U.shape[0], C.shape[0]
-    bn = min(bn, max(8, n))
-    bm = min(bm, max(8, m))
-    Up = _pad_rows(U, bn)
-    Cp = _pad_rows(C, bm)
-    d2d, d3d = _dist.pairwise_dist(Up, Cp, bn=bn, bm=bm, interpret=interpret)
-    return d2d[:n, :m], d3d[:n, :m]
 
 
 def fused_sinr(U, C, Pw, *, pathgain_fn, noise_w: float, boresight=None,

@@ -161,7 +161,7 @@ def _dense_max(data, seg, n_seg: int, fill):
 #: row and bin (0.39 ms for 6.25M rows x 57 bins, 42 us for 262k x 57),
 #: so they break even at 3,000-8,000 bins; 1,024 keeps the dense pass
 #: three times or more below the scatter at any row count, and leaves
-#: 4,096-cell fields (``crrm_ppp``'s ``net_256k``) on the scatter.
+#: fields of 4,096 cells or more on the scatter.
 DENSE_MAX_SEGMENTS = 1024
 
 

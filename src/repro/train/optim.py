@@ -1,18 +1,17 @@
 """Optimizers + LR schedules, built from scratch (no optax in this stack).
 
-Each optimizer is an (init, update) pair over pytrees; states shard exactly
-like their parameters (the dry-run's memory analysis includes them).
+Each optimizer is an (init, update) pair over pytrees; a state follows
+the tree structure of its parameters.
 
 * ``adamw``     -- the default; f32 moments.
 * ``adafactor`` -- factored second moment: O(n+m) state per (n, m) matrix
-                   instead of O(n*m); the memory lever for the biggest cells.
+                   instead of O(n*m).
 * ``sgdm``      -- baseline.
 """
 from __future__ import annotations
 
 import dataclasses
-import math
-from typing import Callable, NamedTuple
+from typing import Callable
 
 import jax
 import jax.numpy as jnp

@@ -1,13 +1,11 @@
-"""Checkpointing (atomic/keep-k/async/restore) and data-pipeline tests."""
+"""Checkpointing tests: atomic writes, keep-k, async save, restore."""
 import os
-import time
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.train import checkpoint as ckpt
-from repro.train.data import MemmapLM, Prefetcher, SyntheticLM
 
 
 def _tree(step):
@@ -51,35 +49,3 @@ def test_restore_rejects_structure_mismatch(tmp_path):
     ckpt.save(d, 1, _tree(1))
     with pytest.raises(ckpt.CheckpointCorrupt):
         ckpt.restore(d, 1, {"different": jnp.zeros(3)})
-
-
-def test_synthetic_deterministic_per_step():
-    src = SyntheticLM(vocab_size=100, batch=4, seq_len=16, seed=3)
-    a = src.batch_at(5)
-    b = src.batch_at(5)
-    np.testing.assert_array_equal(a["tokens"], b["tokens"])
-    c = src.batch_at(6)
-    assert not np.array_equal(a["tokens"], c["tokens"])
-    # labels are next-token targets
-    np.testing.assert_array_equal(a["labels"][:, :-1], a["tokens"][:, 1:])
-
-
-def test_memmap_reader(tmp_path):
-    path = str(tmp_path / "tokens.bin")
-    np.arange(10_000, dtype=np.int32).tofile(path)
-    ds = MemmapLM(path, batch=2, seq_len=8)
-    b0 = ds.batch_at(0)
-    assert b0["tokens"].shape == (2, 8)
-    np.testing.assert_array_equal(b0["labels"], b0["tokens"] + 1)
-
-
-def test_prefetcher_orders_steps():
-    src = SyntheticLM(vocab_size=50, batch=2, seq_len=4, seed=0)
-    pf = Prefetcher(src, start_step=10, depth=2)
-    try:
-        steps = [pf.next()[0] for _ in range(4)]
-        assert steps == [10, 11, 12, 13]
-        np.testing.assert_array_equal(pf.next()[1]["tokens"],
-                                      src.batch_at(14)["tokens"])
-    finally:
-        pf.close()

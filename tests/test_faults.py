@@ -192,11 +192,6 @@ def test_faults_compose_with_churn_and_vmap():
         "vmapped episodes did not diverge"
 
 
-def test_faults_rejected_with_relax():
-    with pytest.raises(ValueError, match="relax"):
-        CRRM(_params()).episode_fns(faults=STORM, relax=0.5)
-
-
 def test_fault_params_validation():
     with pytest.raises(ValueError, match="FaultConfig"):
         _params(faults="storm")

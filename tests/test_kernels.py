@@ -22,17 +22,6 @@ def _net(key, n, m, k, extent=5000.0):
     return U, C, jnp.full((m, k), 5.0)
 
 
-@pytest.mark.parametrize("n,m", [(16, 16), (100, 37), (256, 130), (33, 257)])
-def test_pairwise_dist_vs_ref(n, m):
-    U, C, _ = _net(jax.random.PRNGKey(n * m), n, m, 1)
-    d2a, d3a = ops.pairwise_dist(U, C, bn=32, bm=64)
-    d2r, d3r = ref.pairwise_dist_ref(U, C)
-    np.testing.assert_allclose(np.asarray(d3a), np.asarray(d3r),
-                               rtol=1e-4, atol=0.2)
-    np.testing.assert_allclose(np.asarray(d2a), np.asarray(d2r),
-                               rtol=1e-4, atol=0.2)
-
-
 @pytest.mark.parametrize("model", ["power_law", "UMa", "RMa", "InH"])
 @pytest.mark.parametrize("n,m,k", [(64, 32, 1), (100, 67, 3)])
 def test_fused_sinr_vs_ref(model, n, m, k):

@@ -8,6 +8,10 @@ import pytest
 from repro.core.crrm import CRRM
 from repro.core.params import CRRM_parameters
 from repro.mac import engine as mac_engine
+from repro.sim import scenarios
+from repro.sim.radio import RelaxConfig
+
+import engine_regimes
 
 
 def _sim(**kw):
@@ -64,14 +68,27 @@ def test_harq_retx_count_never_exceeds_max():
                 assert (np.asarray(sim._harq_bits) == 0).all()
 
 
-def test_harq_bler_zero_machine_is_bitexact_with_fast_path():
+def _harq_sim(name):
+    """A poisson-traffic base network, or a registry scenario shrunk to
+    24 UEs x 6 cells -- both at bler=0."""
+    if name == "base":
+        return _sim(traffic_model="poisson", seed=5)
+    return CRRM(scenarios.make_scenario(name, n_ues=24, n_cells=6,
+                                        harq_bler=0.0))
+
+
+@pytest.mark.parametrize("radio_mode", ["dense", "incremental"])
+@pytest.mark.parametrize("name", ("base",) + scenarios.scenario_names())
+def test_harq_bler_zero_machine_is_bitexact_with_fast_path(name, radio_mode):
     """The HARQ machine at bler=0 must reproduce the HARQ-free (PR-1)
-    episode bit-exactly -- same grants, same drains, same PRNG streams."""
+    episode bit-exactly -- same grants, same drains, same PRNG streams --
+    on every registry scenario and in both radio modes."""
     key = jax.random.PRNGKey(42)
-    a = _sim(traffic_model="poisson", seed=5)
-    b = _sim(traffic_model="poisson", seed=5)
-    t_fast = np.asarray(a.run_episode(n_tti=100, key=key, use_harq=False))
-    t_machine = np.asarray(b.run_episode(n_tti=100, key=key, use_harq=True))
+    a, b = _harq_sim(name), _harq_sim(name)
+    t_fast = np.asarray(a.run_episode(n_tti=100, key=key, use_harq=False,
+                                      radio_mode=radio_mode))
+    t_machine = np.asarray(b.run_episode(n_tti=100, key=key, use_harq=True,
+                                         radio_mode=radio_mode))
     np.testing.assert_array_equal(t_fast, t_machine)
     np.testing.assert_array_equal(np.asarray(a.get_backlog()),
                                   np.asarray(b.get_backlog()))
@@ -298,6 +315,59 @@ def test_unknown_policy_rejected():
                            jnp.ones((2, 1), jnp.int32),
                            jnp.zeros(2, jnp.int32), 1, 4, 0,
                            jnp.zeros((2, 1)))
+
+
+_INC = dict(radio_mode="incremental")
+_RELAX = RelaxConfig()
+
+#: (id, CRRM_parameters overrides, episode_fns switches, the reason the
+#: error must state); ``mesh`` names the axes of a one-device mesh
+_REJECTIONS = [
+    ("bad_radio_mode", {}, dict(radio_mode="fancy"),
+     r"radio_mode must be 'dense' or 'incremental'; got 'fancy'"),
+    ("incremental_per_tti_fading", dict(rayleigh_fading=True),
+     dict(per_tti_fading=True, **_INC),
+     r"per-TTI fading redraw dirties every UE row"),
+    ("faults_relax", {}, dict(faults=engine_regimes.FAULTS, relax=_RELAX),
+     r"outage tx mask is a hard discontinuity"),
+    ("relax_mesh", {}, dict(relax=_RELAX, mesh=("ue",)),
+     r"relax= \(differentiable relaxations\) is single-device"),
+    ("relax_churn", {}, dict(relax=_RELAX, churn=engine_regimes.CHURN),
+     r"no gradient path through births"),
+    ("relax_incremental", {}, dict(relax=_RELAX, **_INC),
+     r"relax= requires radio_mode='dense'"),
+    ("churn_mesh", {}, dict(churn=engine_regimes.CHURN, mesh=("ue",)),
+     r"birth-death churn is single-host"),
+    ("cell_axis_without_mesh", {}, dict(cell_axis=("cell",)),
+     r"cell_axis= requires mesh="),
+    ("bad_inc_backend", {}, dict(inc_backend="cuda", **_INC),
+     r"inc_backend must be None, 'auto', 'xla' or 'pallas'; got 'cuda'"),
+    ("pallas_handover", dict(ho_enabled=True),
+     dict(inc_backend="pallas", **_INC),
+     r"cannot express this configuration: handover regimes"),
+    ("pallas_faults", {},
+     dict(inc_backend="pallas", faults=engine_regimes.FAULTS, **_INC),
+     r"cannot express this configuration: cell fault transitions"),
+    ("pallas_cell_axis", {},
+     dict(inc_backend="pallas", mesh=("ue", "cell"), cell_axis=("cell",),
+          **_INC),
+     r"cannot express this configuration: the fused kernel's attachment"),
+]
+
+
+@pytest.mark.parametrize("params_kw,fns_kw,reason",
+                         [pytest.param(*c[1:], id=c[0]) for c in _REJECTIONS])
+def test_episode_fns_rejects_incompatible_switches(params_kw, fns_kw,
+                                                   reason):
+    """Every switch combination make_episode_fns documents as unsupported
+    raises a ValueError that says why, before anything is traced."""
+    fns_kw = dict(fns_kw)
+    if "mesh" in fns_kw:
+        axes = fns_kw["mesh"]
+        fns_kw["mesh"] = jax.sharding.Mesh(
+            np.asarray(jax.devices()[:1]).reshape((1,) * len(axes)), axes)
+    with pytest.raises(ValueError, match=reason):
+        _sim(**params_kw).episode_fns(**fns_kw)
 
 
 def test_graph_sees_per_rb_spectral_efficiency():

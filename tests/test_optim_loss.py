@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 
 from repro.train import optim
-from repro.train.loss import chunked_cross_entropy, cross_entropy
 
 
 def _quadratic_params(key):
@@ -56,32 +55,3 @@ def test_warmup_cosine_schedule():
     np.testing.assert_allclose(float(lr(10)), 1e-3, rtol=1e-5)
     assert float(lr(5)) == pytest.approx(5e-4, rel=1e-4)
     assert float(lr(100)) < float(lr(50)) < float(lr(10))
-
-
-def test_chunked_ce_matches_full():
-    key = jax.random.PRNGKey(0)
-    b, s, d, v = 2, 16, 8, 32
-    feats = jax.random.normal(key, (b, s, d))
-    W = jax.random.normal(jax.random.PRNGKey(1), (d, v)) * 0.3
-    labels = jax.random.randint(jax.random.PRNGKey(2), (b, s), 0, v)
-
-    def full(f):
-        return cross_entropy(f @ W, labels)[0]
-
-    def chunked(f):
-        return chunked_cross_entropy(lambda x: x @ W, f, labels, chunk=4)[0]
-
-    np.testing.assert_allclose(float(full(feats)), float(chunked(feats)),
-                               rtol=1e-6)
-    g1 = jax.grad(full)(feats)
-    g2 = jax.grad(chunked)(feats)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=2e-5,
-                               atol=1e-6)
-
-
-def test_ce_masking():
-    logits = jnp.zeros((1, 4, 8))
-    labels = jnp.zeros((1, 4), jnp.int32)
-    mask = jnp.asarray([[1.0, 1.0, 0.0, 0.0]])
-    loss, m = cross_entropy(logits, labels, mask=mask, z_loss=0.0)
-    np.testing.assert_allclose(float(loss), np.log(8), rtol=1e-5)

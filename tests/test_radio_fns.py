@@ -245,7 +245,7 @@ def test_sharded_episode_matches_single_device_two_device_mesh():
     """ISSUE-4 acceptance: shard_mapped episodes on a 2-device host mesh
     match the single-device rollout (bitwise for rr/max_cqi, 1e-5 for
     pf).  XLA device count must be forced before jax initialises, so this
-    runs in a fresh subprocess (same pattern as test_distributed_crrm)."""
+    runs in a fresh subprocess."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     out = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT],

@@ -17,6 +17,8 @@ from repro.core.crrm import CRRM
 from repro.core.params import CRRM_parameters
 from repro.sim import mobility, radio, scenarios
 
+import engine_regimes
+
 ROOT = Path(__file__).resolve().parents[1]
 
 
@@ -32,18 +34,31 @@ def _pair(params):
 
 
 # -------------------------------------------- dense == incremental (scan)
-@pytest.mark.parametrize("name", scenarios.scenario_names())
-def test_incremental_matches_dense_across_scenarios(name):
+#: the engine switches of each regime on top of the scenario's preset;
+#: "action" rolls a fixed power action over static geometry
+REGIMES = {"movers": engine_regimes.MOVERS,
+           **engine_regimes.CHURN_FAULTS,
+           "action": dict(mobility_step_m=0)}
+
+
+@pytest.mark.parametrize("name,regime",
+                         engine_regimes.cases(REGIMES, "movers"))
+def test_incremental_matches_dense_across_scenarios(name, regime):
     """Tentpole acceptance: the incremental rollout reproduces the dense
-    rollout on every registry scenario at 25% per-TTI dirtiness (the
-    sharded gate's 1e-5, bit-exact positions)."""
+    rollout on every registry scenario in every switch regime -- 25%
+    per-TTI window movers, birth-death churn, cell faults, both, and a
+    scan-constant power action (the sharded gate's 1e-5, bit-exact
+    positions)."""
     a, b = _pair(_shrink(name))
     key = jax.random.PRNGKey(0)
-    kw = dict(mobility_step_m=25.0, mobility_move_frac=0.25)
+    kw = REGIMES[regime]
     f1 = a.episode_fns(radio_mode="dense", **kw)
     f2 = b.episode_fns(radio_mode="incremental", **kw)
-    s1, t1 = f1.rollout(a.episode_static(), a.init_episode_state(key), 20)
-    s2, t2 = f2.rollout(b.episode_static(), b.init_episode_state(key), 20)
+    s0a = engine_regimes.start(a, key, kw)
+    s0b = engine_regimes.start(b, key, kw)
+    act = (jnp.asarray(a.P._data) * 0.6,) if regime == "action" else ()
+    s1, t1 = f1.rollout(a.episode_static(), s0a, 20, *act)
+    s2, t2 = f2.rollout(b.episode_static(), s0b, 20, *act)
     np.testing.assert_allclose(np.asarray(t2), np.asarray(t1),
                                rtol=1e-5, atol=1e-2)
     np.testing.assert_array_equal(np.asarray(s2.U), np.asarray(s1.U))
@@ -107,15 +122,6 @@ def test_incremental_vmaps_over_batched_episodes():
     _, t2 = jax.jit(jax.vmap(lambda s: f2.rollout(st_b, s, 10)))(batch)
     np.testing.assert_allclose(np.asarray(t2), np.asarray(t1),
                                rtol=1e-5, atol=1e-2)
-
-
-def test_incremental_rejects_per_tti_fading():
-    sim = CRRM(CRRM_parameters(n_ues=8, n_cells=2, rayleigh_fading=True,
-                               pathloss_model_name="UMa"))
-    with pytest.raises(ValueError, match="per_tti_fading"):
-        sim.episode_fns(per_tti_fading=True, radio_mode="incremental")
-    with pytest.raises(ValueError, match="radio_mode"):
-        sim.episode_fns(radio_mode="fancy")
 
 
 def test_env_incremental_action_matches_dense_env():
@@ -394,12 +400,6 @@ def test_engine_inc_backend_pallas_matches_xla():
     with pytest.raises(ValueError, match="cannot express"):
         ho.episode_fns(radio_mode="incremental", inc_backend="pallas")
     ho.episode_fns(radio_mode="incremental", inc_backend="auto")  # falls back
-
-
-def test_cell_axis_requires_mesh():
-    sim = CRRM(_shrink("dense_urban"))
-    with pytest.raises(ValueError, match="mesh"):
-        sim.episode_fns(cell_axis=("cell",))
 
 
 # ------------------------------------- donated rollout executable (ISSUE 9)

@@ -2,8 +2,7 @@
 
 A CRRM network lives through a mobility episode with power reconfiguration;
 the smart engine must agree with the full-recompute engine at every step,
-while doing strictly less work (the paper's core claim), and the serving
-engine must generate deterministically (the LM side of the framework).
+while doing strictly less work (the paper's core claim).
 """
 import jax
 import numpy as np
@@ -37,28 +36,3 @@ def test_full_episode_smart_vs_full():
     assert s_counts["D"][1] > 0          # row updates happened
     assert f_counts["D"][1] == 0         # control never row-updates
     assert s_counts["D"][0] < f_counts["D"][0]
-
-
-def test_serving_engine_generates():
-    from repro.configs import get_config
-    from repro.models.registry import make_arch
-    from repro.parallel.mesh import make_host_mesh
-    from repro.serve.engine import ServeEngine
-
-    cfg = get_config("qwen1.5-0.5b", reduced=True)
-    arch = make_arch(cfg)
-    eng = ServeEngine(arch, make_host_mesh(1, 1), batch_slots=2, max_len=64)
-    r1 = eng.submit(np.arange(5) % cfg.vocab_size, max_new_tokens=6)
-    r2 = eng.submit(np.arange(9) % cfg.vocab_size, max_new_tokens=4)
-    out = eng.run()
-    assert len(out["results"][r1.rid]) == 6
-    assert len(out["results"][r2.rid]) == 4
-    assert out["tokens_per_s"] > 0
-
-    # greedy decoding is deterministic
-    eng2 = ServeEngine(arch, make_host_mesh(1, 1), batch_slots=2, max_len=64)
-    r1b = eng2.submit(np.arange(5) % cfg.vocab_size, max_new_tokens=6)
-    r2b = eng2.submit(np.arange(9) % cfg.vocab_size, max_new_tokens=4)
-    out2 = eng2.run()
-    assert out2["results"][r1b.rid] == out["results"][r1.rid]
-    assert out2["results"][r2b.rid] == out["results"][r2.rid]

@@ -21,6 +21,8 @@ from repro.core.params import CRRM_parameters
 from repro.obs import CompileCounter, RetraceWatch, Telemetry, summarize
 from repro.sim import scenarios
 
+import engine_regimes
+
 
 def _shrink(name, **kw):
     base = dict(n_ues=24, n_cells=6)
@@ -33,19 +35,31 @@ def _pair(params):
 
 
 # ------------------------------------------ on == off, bitwise, everywhere
-@pytest.mark.parametrize("name", scenarios.scenario_names())
-def test_telemetry_is_structural_noop_across_scenarios(name):
+#: the engine switches that put a scenario into each regime ("dense" is
+#: the scenario's own preset)
+REGIMES = {"dense": {},
+           "incremental": dict(radio_mode="incremental",
+                               **engine_regimes.MOVERS),
+           **engine_regimes.CHURN_FAULTS}
+
+
+@pytest.mark.parametrize("name,regime",
+                         engine_regimes.cases(REGIMES, "dense"))
+def test_telemetry_is_structural_noop_across_scenarios(name, regime):
     """Acceptance: telemetry=True reproduces the telemetry=False
     trajectory AND final state bit-exactly on every registry scenario,
-    with the per-TTI KPI stack returned."""
+    in every switch regime, with the per-TTI KPI stack returned."""
     a, b = _pair(_shrink(name))
     key = jax.random.PRNGKey(0)
-    f_off, f_on = a.episode_fns(), b.episode_fns(telemetry=True)
-    s0a = a.init_episode_state(key)
-    s0b = b.init_episode_state(key)
+    kw = REGIMES[regime]
+    f_off, f_on = a.episode_fns(**kw), b.episode_fns(telemetry=True, **kw)
+    s0a = engine_regimes.start(a, key, kw)
+    s0b = engine_regimes.start(b, key, kw)
     s1, t1 = f_off.rollout(a.episode_static(), s0a, 15)
     s2, t2, telem = f_on.rollout(b.episode_static(), s0b, 15)
     np.testing.assert_array_equal(np.asarray(t2), np.asarray(t1))
+    assert (jax.tree_util.tree_structure(s1)
+            == jax.tree_util.tree_structure(s2))
     for l1, l2 in zip(jax.tree_util.tree_leaves(s1),
                       jax.tree_util.tree_leaves(s2)):
         np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
@@ -53,6 +67,11 @@ def test_telemetry_is_structural_noop_across_scenarios(name):
     assert telem.jain.shape == (15,)
     assert telem.served_bits.shape == (15, b.n_cells)
     assert telem.granted_rb.shape == (15, b.n_cells)
+    # the regime's processes really ran: a UE was born, a cell went dark
+    if "churn" in kw:
+        assert (np.diff(np.asarray(telem.active_ues)) > 0).any()
+    if "faults" in kw:
+        assert np.asarray(telem.cells_down).any()
 
 
 def test_telemetry_kpis_are_consistent_with_trajectory():
